@@ -50,6 +50,8 @@ def run():
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for name, val, note in run():
         print(f"{name},{val},{note}")
 

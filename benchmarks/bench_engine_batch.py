@@ -109,6 +109,8 @@ def write_json(result: dict) -> None:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()

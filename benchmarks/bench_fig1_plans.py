@@ -77,6 +77,8 @@ def run(store=None, depths=(0.1, 0.3, 0.5, 0.7, 0.9), reps=3,
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for name, ops, ms in run():
         print(f"{name},{ms*1e3:.1f},ops_applied={ops}")
 

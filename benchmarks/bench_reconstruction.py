@@ -43,10 +43,12 @@ def run(n_nodes=1024, reps=3, with_kernel=False):
         rows.append((f"recon/speedup@{frac}", seq / vec))
         if with_kernel:
             from repro.kernels.delta_apply import delta_apply
+            interpret = jax.default_backend() == "cpu"
             k = _timeit(lambda: delta_apply(
                 store.current, d, store.t_cur, t_q, tile=256,
-                cap=1 << 14)[0].adj, reps)
-            rows.append((f"recon/pallas_interpret@{frac}", k))
+                cap=1 << 14, interpret=interpret)[0].adj, reps)
+            rows.append((f"recon/pallas{'_interpret' * interpret}@{frac}",
+                         k))
 
     # materialization: reconstruct at random times with/without snapshots
     store_m = build_store(n_nodes, EvolutionParams(
@@ -78,6 +80,8 @@ def run(n_nodes=1024, reps=3, with_kernel=False):
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for name, ms in run():
         print(f"{name},{ms*1e3:.1f},")
 

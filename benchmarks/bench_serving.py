@@ -240,6 +240,8 @@ def run_config(cfg_name: str) -> dict:
 
 
 def main() -> int:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="down-scaled run only (CI fast lane)")

@@ -195,6 +195,8 @@ def run_sweep(cfg: dict) -> dict:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="down-scaled sweep only (CI fast lane)")

@@ -27,6 +27,8 @@ def run(seed=7):
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows, dt, _ = run()
     for name, got, target, relerr in rows:
         print(f"{name},{got},target={target},rel_err={relerr:.4f}")

@@ -7,6 +7,11 @@ device_count, git sha), so artifacts are comparable across PRs.  Any
 section raising an exception is reported AND makes the driver exit
 non-zero — a red benchmark run never looks green.
 
+Every section runs in this one process.  The device-count sweep
+(``bench_distributed.py``) starts one child per device count, and a
+child cannot use a chip this process already holds, so it runs on its
+own.
+
   PYTHONPATH=src python -m benchmarks.run [--fast]
 """
 from __future__ import annotations
@@ -17,11 +22,11 @@ import traceback
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="smaller graphs / fewer reps")
-    ap.add_argument("--skip-distributed", action="store_true",
-                    help="skip the multi-process device-count sweep")
     args = ap.parse_args()
 
     failures = []
@@ -89,23 +94,6 @@ def main() -> None:
             bench_engine_batch.write_json(result)
 
     section("engine batched serving", eb)
-
-    # Multi-device serving (qps vs device count, subprocess sweep)
-    from benchmarks import bench_distributed
-
-    def dist():
-        dargs = argparse.Namespace(
-            n_nodes=150 if args.fast else 300,
-            n_queries=64 if args.fast else 256,
-            reps=2 if args.fast else 3)
-        rows, results = bench_distributed.run(dargs)
-        for name, val, note in rows:
-            print(f"{name},{val},{note}")
-        if not args.fast:
-            bench_distributed.write_json(results)
-
-    if not args.skip_distributed:
-        section("distributed serving", dist)
 
     # Kernels
     from benchmarks import bench_kernels

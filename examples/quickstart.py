@@ -12,8 +12,11 @@ import tempfile
 import jax.numpy as jnp
 
 from repro.api import GraphSession, Op, Query
+from repro.compile_cache import enable_compile_cache
 from repro.core import (ADD_EDGE, ADD_NODE, REM_EDGE, reconstruct_dense,
                         reconstruct_sequential)
+
+enable_compile_cache()
 
 root = tempfile.mkdtemp(prefix="quickstart_graph_")
 

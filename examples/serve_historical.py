@@ -32,6 +32,8 @@ from repro.core.reconstruct import degree_series
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--nodes", type=int, default=1500)
     ap.add_argument("--queries", type=int, default=64)

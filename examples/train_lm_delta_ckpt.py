@@ -25,6 +25,8 @@ from repro.launch.train import train
 
 
 def main():
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--preset", choices=["tiny", "100m"], default="tiny",

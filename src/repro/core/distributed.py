@@ -35,16 +35,13 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from repro.core.delta import ADD_EDGE, Delta
 from repro.core.graph import DenseGraph, EdgeGraph
 from repro.core.plans import masked_aggregate
+from repro.core.queries import avg_degree_of, density_of
 from repro.core.reconstruct import _lww_decide
 from repro.sharding.graph import (AXIS, batch_specs,  # noqa: F401
                                   graph_mesh, replicate, shard_rows,
@@ -139,12 +136,9 @@ def _row_finalize(tot, measure: str):
     n = tot[..., 0]
     e = tot[..., 1] // 2
     if measure == "density":
-        nf = n.astype(jnp.float32)
-        ef = e.astype(jnp.float32)
-        return jnp.where(nf > 1, 2.0 * ef / (nf * (nf - 1.0)), 0.0)
+        return density_of(n, e)
     if measure == "avg_degree":
-        nf = jnp.maximum(n, 1).astype(jnp.float32)
-        return 2.0 * e.astype(jnp.float32) / nf
+        return avg_degree_of(n, e)
     raise ValueError(f"measure {measure} is not row-decomposable")
 
 
@@ -263,12 +257,9 @@ def _slot_finalize(tot, measure: str):
     n = tot[..., 0]
     e = tot[..., 1]
     if measure == "density":
-        nf = n.astype(jnp.float32)
-        ef = e.astype(jnp.float32)
-        return jnp.where(nf > 1, 2.0 * ef / (nf * (nf - 1.0)), 0.0)
+        return density_of(n, e)
     if measure == "avg_degree":
-        nf = jnp.maximum(n, 1).astype(jnp.float32)
-        return 2.0 * e.astype(jnp.float32) / nf
+        return avg_degree_of(n, e)
     raise ValueError(f"measure {measure} is not slot-decomposable")
 
 

@@ -26,7 +26,7 @@ from repro.core.graph import DenseGraph, EdgeGraph
 from repro.core.index import NodeIndex, gather_node_ops, gather_window
 from repro.core.partial import partial_reconstruct, seed_mask
 from repro.core.queries import (EDGE_GLOBAL_MEASURES, EDGE_NODE_MEASURES,
-                                GLOBAL_MEASURES, NODE_MEASURES)
+                                GLOBAL_MEASURES, NODE_MEASURES, div_rn)
 from repro.core.reconstruct import (node_degree_series, reconstruct_dense,
                                     reconstruct_edge,
                                     reconstruct_sequential)
@@ -117,10 +117,10 @@ def _measure(g, q: Query):
 def _aggregate(vals: jax.Array, agg: Aggregate):
     if agg == "mean":
         # Explicit sum/width (not jnp.mean, which lowers to a
-        # reciprocal-multiply): keeps the scalar path bit-identical to
-        # the engine's masked batched aggregation.
+        # reciprocal-multiply), correctly rounded: keeps the scalar path
+        # bit-identical to the engine's masked batched aggregation.
         v = vals.astype(jnp.float32)
-        return jnp.sum(v) / v.shape[0]
+        return div_rn(jnp.sum(v), jnp.float32(v.shape[0]))
     return jnp.min(vals) if agg == "min" else jnp.max(vals)
 
 
@@ -232,11 +232,12 @@ def masked_aggregate(vals: jax.Array, width, num_buckets: int,
     (the tail is padding).  Shared by the scalar hybrid plan and the
     engine's batched executors: one definition keeps the bit-identity
     guarantee between the scalar and batched paths (exact f32 sum of
-    integer values, true division by the width — not ``jnp.mean``,
-    which lowers to a reciprocal-multiply)."""
+    integer values, correctly rounded division by the width — not
+    ``jnp.mean``, which lowers to a reciprocal-multiply)."""
     keep = jnp.arange(num_buckets, dtype=jnp.int32) < width
     if agg == "mean":
-        return jnp.sum(jnp.where(keep, vals, 0).astype(jnp.float32)) / width
+        return div_rn(jnp.sum(jnp.where(keep, vals, 0).astype(jnp.float32)),
+                      jnp.asarray(width, jnp.float32))
     big = jnp.asarray(1 << 30, vals.dtype)
     if agg == "min":
         return jnp.min(jnp.where(keep, vals, big))

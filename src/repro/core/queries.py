@@ -20,6 +20,56 @@ from repro.core.graph import DenseGraph, EdgeGraph
 
 INF = jnp.int32(0x3FFFFFFF)
 
+
+# ---------------------------------------------------------------------------
+# Exact f32 finalization
+# ---------------------------------------------------------------------------
+
+
+def div_rn(num: jax.Array, den: jax.Array) -> jax.Array:
+    """``num / den`` in f32, correctly rounded on every backend.
+
+    XLA's f32 divide on a TPU is faithful (within one ulp), not
+    correctly rounded, so a float measure would differ in its last bit
+    between a CPU and a TPU and from any IEEE reference.  Here the
+    significands are divided exactly by long division in int32 and the
+    quotient rounded to nearest even.  Operands are positive normal f32
+    or ``num == 0`` (counts, sums of counts and their products); a zero
+    ``den`` gives an unspecified value, so callers mask it."""
+    a = jax.lax.bitcast_convert_type(num.astype(jnp.float32), jnp.int32)
+    b = jax.lax.bitcast_convert_type(den.astype(jnp.float32), jnp.int32)
+    ma = (a & 0x7FFFFF) | 0x800000
+    mb = (b & 0x7FFFFF) | 0x800000
+    below = ma < mb                      # quotient significand < 1
+    r = jnp.where(below, ma << 1, ma)
+    q = jnp.zeros_like(r)
+    for _ in range(25):                  # 24 significand bits + guard
+        bit = (r >= mb).astype(jnp.int32)
+        q = (q << 1) | bit
+        r = (r - bit * mb) << 1
+    up = (q & 1) & ((r != 0) | ((q >> 1) & 1)).astype(jnp.int32)
+    q = (q >> 1) + up                    # round to nearest, ties even
+    carry = q >> 24                      # rounding overflowed to 2.0
+    exp = ((a >> 23) - (b >> 23) + 127 - below.astype(jnp.int32) + carry)
+    bits = (exp << 23) | ((q >> carry) & 0x7FFFFF)
+    out = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    return jnp.where(a == 0, jnp.float32(0.0), out)
+
+
+def avg_degree_of(n_nodes: jax.Array, n_edges: jax.Array) -> jax.Array:
+    """2·|E| / max(|V|, 1) from the integer counts — the one
+    finalization every layout, shard mode and sweep uses."""
+    n = jnp.maximum(n_nodes, 1).astype(jnp.float32)
+    return div_rn(2.0 * n_edges.astype(jnp.float32), n)
+
+
+def density_of(n_nodes: jax.Array, n_edges: jax.Array) -> jax.Array:
+    """2·|E| / (|V|·(|V| − 1)), 0 below two nodes."""
+    n = n_nodes.astype(jnp.float32)
+    e = n_edges.astype(jnp.float32)
+    return jnp.where(n > 1, div_rn(2.0 * e, n * (n - 1.0)), 0.0)
+
+
 # ---------------------------------------------------------------------------
 # Node-centric measures
 # ---------------------------------------------------------------------------
@@ -50,8 +100,7 @@ def induced_avg_degree(g: DenseGraph, v) -> jax.Array:
     the paper's §3.2.3 multi-pass hybrid example."""
     m = induced_subgraph_mask(g, v)
     sub = g.induced(m)
-    nn = jnp.maximum(sub.num_nodes(), 1)
-    return (2.0 * sub.num_edges()) / nn
+    return avg_degree_of(sub.num_nodes(), sub.num_edges())
 
 
 def in_k_core(g: DenseGraph, v, k: int) -> jax.Array:
@@ -85,14 +134,11 @@ def num_edges(g: DenseGraph):
 
 
 def density(g: DenseGraph) -> jax.Array:
-    n = g.num_nodes().astype(jnp.float32)
-    e = g.num_edges().astype(jnp.float32)
-    return jnp.where(n > 1, 2.0 * e / (n * (n - 1.0)), 0.0)
+    return density_of(g.num_nodes(), g.num_edges())
 
 
 def avg_degree(g: DenseGraph) -> jax.Array:
-    n = jnp.maximum(g.num_nodes(), 1).astype(jnp.float32)
-    return 2.0 * g.num_edges().astype(jnp.float32) / n
+    return avg_degree_of(g.num_nodes(), g.num_edges())
 
 
 # Registered degree-distribution bin count: degrees past the last bin
@@ -184,8 +230,13 @@ def diameter(g: DenseGraph, num_sources: int = 0, max_iters: int = 64):
 
 
 def triangle_count(g: DenseGraph) -> jax.Array:
+    """trace(A³)/6 as sum((A @ A) ⊙ A)/6 for the symmetric A.  The one
+    product multiplies 0/1 entries, exact at any matmul precision (the
+    TPU's default rounds f32 inputs to bf16, so a second product over
+    path counts above 256 would not be); the rest is int32."""
     a = g.adj.astype(jnp.float32)
-    return (jnp.trace(a @ a @ a) / 6.0).astype(jnp.int32)
+    paths2 = (a @ a).astype(jnp.int32)
+    return jnp.sum(jnp.where(g.adj, paths2, 0)) // 6
 
 
 @partial(jax.jit, static_argnames=("iters",))
@@ -246,14 +297,11 @@ def edge_num_edges(g: EdgeGraph) -> jax.Array:
 
 
 def edge_density(g: EdgeGraph) -> jax.Array:
-    n = g.num_nodes().astype(jnp.float32)
-    e = g.num_edges().astype(jnp.float32)
-    return jnp.where(n > 1, 2.0 * e / (n * (n - 1.0)), 0.0)
+    return density_of(g.num_nodes(), g.num_edges())
 
 
 def edge_avg_degree(g: EdgeGraph) -> jax.Array:
-    n = jnp.maximum(g.num_nodes(), 1).astype(jnp.float32)
-    return 2.0 * g.num_edges().astype(jnp.float32) / n
+    return avg_degree_of(g.num_nodes(), g.num_edges())
 
 
 def edge_degree_distribution(g: EdgeGraph,

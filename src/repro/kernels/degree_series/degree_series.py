@@ -8,12 +8,15 @@ for *all* nodes at once (batched query serving).
 Grid: 1-D over node tiles.  ops.py buckets edge-op endpoint events by
 node tile: entry [local_node, bucket, sign, valid]; bucket B is a
 virtual tail for ops in (t_l, t_cur].  Kernel: scatter-accumulate the
-per-(bucket, node) net counts in VMEM, then a reverse running sum turns
-them into the series:
+per-(bucket, node) net counts in VMEM (one aligned-window
+read-modify-write per event, ``kernels.cell``; the event block is
+field-major (4, cap) in SMEM), then a reverse running sum turns them
+into the series:
 
   degree(v, t_k + b) = deg_cur(v) − Σ_{b' > b} net[b', v]
 
-VMEM per instance: (B+2)·TN·4 bytes of scratch + cap·4·4 op block.
+Per instance: (B+2)·TN·4 bytes of VMEM scratch (rows rounded up to a
+multiple of 8) and a 4·cap·4-byte SMEM event block.
 """
 from __future__ import annotations
 
@@ -24,31 +27,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.cell import padded_rows, update_cell
+
 
 def _kernel(ops_ref, deg_ref, out_ref, net_ref, *, cap: int,
             num_buckets: int):
     net_ref[...] = jnp.zeros_like(net_ref)
 
     def scatter(j, _):
-        ln = ops_ref[0, j, 0]
-        b = ops_ref[0, j, 1]
-        sign = ops_ref[0, j, 2]
-        valid = ops_ref[0, j, 3]
-        cur = pl.load(net_ref, (pl.ds(b, 1), pl.ds(ln, 1)))
-        pl.store(net_ref, (pl.ds(b, 1), pl.ds(ln, 1)),
-                 cur + jnp.where(valid > 0, sign, 0).reshape(1, 1))
+        @pl.when(ops_ref[0, 3, j] > 0)
+        def _():
+            sign = ops_ref[0, 2, j]
+            update_cell(net_ref, ops_ref[0, 1, j], ops_ref[0, 0, j],
+                        lambda w: w + sign)
         return 0
 
     jax.lax.fori_loop(0, cap, scatter, 0)
 
-    def rev(j, acc):
-        b = num_buckets - 1 - j
+    # static row indices: Mosaic refuses dynamic single-row slices
+    acc = jnp.zeros_like(net_ref[0, :])
+    for b in range(num_buckets - 1, -1, -1):
         acc = acc + net_ref[b + 1, :]
         out_ref[b, :] = deg_ref[0, :] - acc
-        return acc
-
-    jax.lax.fori_loop(0, num_buckets, rev,
-                      jnp.zeros_like(net_ref[0, :]), unroll=False)
 
 
 @functools.partial(jax.jit,
@@ -57,8 +57,8 @@ def _kernel(ops_ref, deg_ref, out_ref, net_ref, *, cap: int,
 def degree_series_tiles(deg_cur: jax.Array, tile_ops: jax.Array,
                         tile: int = 256, cap: int = 1024,
                         num_buckets: int = 64,
-                        interpret: bool = True) -> jax.Array:
-    """deg_cur: i32[N]; tile_ops: i32[T, cap, 4] → i32[num_buckets, N]."""
+                        interpret: bool = False) -> jax.Array:
+    """deg_cur: i32[N]; tile_ops: i32[T, 4, cap] → i32[num_buckets, N]."""
     n = deg_cur.shape[0]
     assert n % tile == 0
     grid = (n // tile,)
@@ -66,11 +66,13 @@ def degree_series_tiles(deg_cur: jax.Array, tile_ops: jax.Array,
         functools.partial(_kernel, cap=cap, num_buckets=num_buckets),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, cap, 4), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 4, cap), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, tile), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((num_buckets, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((num_buckets, n), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((num_buckets + 2, tile), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((padded_rows(num_buckets + 2), tile),
+                                   jnp.int32)],
         interpret=interpret,
     )(tile_ops, deg_cur.reshape(1, n))

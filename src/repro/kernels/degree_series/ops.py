@@ -18,9 +18,10 @@ from repro.kernels.degree_series.degree_series import degree_series_tiles
 def bucket_node_events(delta: Delta, n: int, t_k, num_buckets: int,
                        tile: int, cap: int, row0: int = 0,
                        n_valid: int | None = None):
-    """Dense per-node-tile event blocks i32[T, cap, 4]:
-    [local_node, bucket, sign, valid].  Each in-suffix edge op (t > t_k)
-    yields one event per endpoint; bucket = clip(t - t_k, 0, B).
+    """Dense per-node-tile event blocks i32[T, 4, cap], field-major:
+    each entry is the column [local_node, bucket, sign, valid].  Each
+    in-suffix edge op (t > t_k) yields one event per endpoint;
+    bucket = clip(t - t_k, 0, B).
 
     ``row0`` makes the bucketing shard-safe: with ``n`` the *local*
     (tile-padded) node count, only events touching nodes
@@ -51,8 +52,8 @@ def bucket_node_events(delta: Delta, n: int, t_k, num_buckets: int,
     keep = (tid_s < tcount) & (pos < cap)
     entries = jnp.stack([nodes[order] % tile, bs[order], signs[order],
                          jnp.ones_like(pos)], axis=1)
-    blocks = jnp.zeros((tcount + 1, cap, 4), jnp.int32)
-    blocks = blocks.at[jnp.where(keep, tid_s, tcount),
+    blocks = jnp.zeros((tcount + 1, 4, cap), jnp.int32)
+    blocks = blocks.at[jnp.where(keep, tid_s, tcount), :,
                        jnp.clip(pos, 0, cap - 1)].set(
         jnp.where(keep[:, None], entries, 0))
     return blocks[:tcount], overflow
@@ -60,7 +61,7 @@ def bucket_node_events(delta: Delta, n: int, t_k, num_buckets: int,
 
 def degree_series_rows(deg_block: jnp.ndarray, delta: Delta, t_k: int,
                        num_buckets: int, row0: int = 0, tile: int = 256,
-                       cap: int = 1024, interpret: bool = True):
+                       cap: int = 1024, interpret: bool = False):
     """Shard-safe variant: the series for one node block only.
 
     ``deg_block`` is i32[R] — current degrees of nodes
@@ -78,7 +79,7 @@ def degree_series_rows(deg_block: jnp.ndarray, delta: Delta, t_k: int,
 
 def degree_series_kernel(current: DenseGraph, delta: Delta, t_k: int,
                          num_buckets: int, tile: int = 256,
-                         cap: int = 1024, interpret: bool = True):
+                         cap: int = 1024, interpret: bool = False):
     """i32[num_buckets, N]: degrees of every node at t_k + b."""
     return degree_series_rows(current.degrees(), delta, t_k, num_buckets,
                               row0=0, tile=tile, cap=cap,

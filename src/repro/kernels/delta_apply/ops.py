@@ -19,7 +19,8 @@ from repro.kernels.delta_apply.delta_apply import delta_apply_tiles
 def bucket_ops(delta: Delta, n: int, t_lo, t_hi, tile: int, cap: int,
                forward: bool, n_rows: int | None = None, row0: int = 0,
                n_valid_rows: int | None = None):
-    """Build the dense per-tile op blocks i32[Tr, Tc, cap, 4].
+    """Build the dense per-tile op blocks i32[Tr, Tc, 4, cap],
+    field-major: each entry is the column [lu, lv, value, valid].
 
     Every in-window edge op contributes two entries ((u,v) and (v,u)).
     Entries are ordered so sequential overwrite == last-writer-wins:
@@ -73,11 +74,11 @@ def bucket_ops(delta: Delta, n: int, t_lo, t_hi, tile: int, cap: int,
     dst_p = jnp.clip(pos, 0, cap - 1)
     entries = jnp.stack([lr[perm] % tile, vs[perm] % tile, vals[perm],
                          jnp.ones_like(dst_p)], axis=1)
-    blocks = jnp.zeros((nt + 1, cap, 4), jnp.int32)
+    blocks = jnp.zeros((nt + 1, 4, cap), jnp.int32)
     keep = (tid_s < nt) & (pos < cap)
-    blocks = blocks.at[jnp.where(keep, dst_t, nt),
+    blocks = blocks.at[jnp.where(keep, dst_t, nt), :,
                        dst_p].set(jnp.where(keep[:, None], entries, 0))
-    return blocks[:nt].reshape(tr, tc, cap, 4), overflow
+    return blocks[:nt].reshape(tr, tc, 4, cap), overflow
 
 
 def _node_mask_lww(nodes, delta: Delta, t_lo, t_hi, forward: bool,
@@ -108,7 +109,7 @@ def _node_mask_lww(nodes, delta: Delta, t_lo, t_hi, forward: bool,
 def delta_apply_row_block(nodes_block: jnp.ndarray, adj_block: jnp.ndarray,
                           delta: Delta, t_anchor: int, t_query: int,
                           row0: int, tile: int = 256, cap: int = 1024,
-                          interpret: bool = True):
+                          interpret: bool = False):
     """Kernel-backed LWW reconstruction of one adjacency *row block*
     (shard-safe: this is what each device of a row-sharded mesh runs).
 
@@ -138,7 +139,7 @@ def delta_apply_row_block(nodes_block: jnp.ndarray, adj_block: jnp.ndarray,
 
 def delta_apply(anchor: DenseGraph, delta: Delta, t_anchor: int,
                 t_query: int, tile: int = 256, cap: int = 1024,
-                interpret: bool = True) -> DenseGraph:
+                interpret: bool = False) -> DenseGraph:
     """Kernel-backed reconstruct_at for DenseGraph (edge part on the
     Pallas kernel, node mask via XLA scatter)."""
     nodes, adj_new, overflow = delta_apply_row_block(

@@ -13,14 +13,15 @@ for either reconstruction direction:
              (the "first op after t′ decides" rule, Definition 5)
 
 Each grid instance owns one VMEM slot tile and replays only its own op
-segment (dense (CAP, 4) int32 block: [local_slot, value, valid, 0]),
-so total work is O(window ops + tiles·pad) and state is O(E) — no N²
-anywhere.  Unlike the dense kernel an edge op contributes ONE entry
+segment (a field-major (4, CAP) int32 block in SMEM: rows [local_slot,
+value, valid, 0]; each op is one aligned-window read-modify-write,
+``kernels.cell``), so total work is O(window ops + tiles·pad) and state
+is O(E) — no N² anywhere.  Unlike the dense kernel an edge op contributes ONE entry
 (its slot), not two (u,v)/(v,u) mirrors.
 
-VMEM budget per instance: TILE·4 bytes (mask tile, int32) + CAP·4·4
-bytes (op block).  Defaults TILE=512, CAP=1024 → ~18 KiB, far under
-the ~16 MiB/core VMEM of a v5e; TILE is kept a multiple of 128 to stay
+Memory per instance: TILE·4 bytes of VMEM (mask tile, int32) and
+4·CAP·4 bytes of SMEM (op block).  Defaults TILE=512, CAP=1024 → 2 KiB
+VMEM and 16 KiB SMEM per buffer; TILE is kept a multiple of 128 to stay
 lane-aligned.
 """
 from __future__ import annotations
@@ -30,19 +31,20 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.cell import update_cell
 
 
 def _kernel(ops_ref, mask_ref, out_ref, *, cap: int):
     out_ref[...] = mask_ref[...]
 
     def body(j, _):
-        ls = ops_ref[0, j, 0]
-        val = ops_ref[0, j, 1]
-        valid = ops_ref[0, j, 2]
-        cur = pl.load(out_ref, (pl.ds(0, 1), pl.ds(ls, 1)))
-        new = jnp.where(valid > 0, val.astype(jnp.int32), cur[0, 0])
-        pl.store(out_ref, (pl.ds(0, 1), pl.ds(ls, 1)),
-                 jnp.full((1, 1), new, jnp.int32))
+        @pl.when(ops_ref[0, 2, j] > 0)
+        def _():
+            val = ops_ref[0, 1, j]
+            update_cell(out_ref, 0, ops_ref[0, 0, j],
+                        lambda w: jnp.full_like(w, val))
         return 0
 
     jax.lax.fori_loop(0, cap, body, 0)
@@ -52,14 +54,14 @@ def _kernel(ops_ref, mask_ref, out_ref, *, cap: int):
                    static_argnames=("tile", "cap", "interpret"))
 def edge_delta_apply_tiles(emask: jax.Array, tile_ops: jax.Array,
                            tile: int = 512, cap: int = 1024,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """Apply pre-bucketed slot-tile op lists to the edge mask.
 
     emask:    i32[E] (0/1) — E a multiple of ``tile``.  A full registry
               for a single-device snapshot; one slot shard of a
               slot-sharded mesh (ops.bucket_slot_ops builds matching
               blocks via ``slot0``).
-    tile_ops: i32[T, cap, 4] — per-tile [local_slot, value, valid, 0]
+    tile_ops: i32[T, 4, cap] — per-tile rows [local_slot, value, valid, 0]
     returns:  i32[E]
     """
     e = emask.shape[0]
@@ -69,7 +71,8 @@ def edge_delta_apply_tiles(emask: jax.Array, tile_ops: jax.Array,
         functools.partial(_kernel, cap=cap),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, cap, 4), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 4, cap), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, tile), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),

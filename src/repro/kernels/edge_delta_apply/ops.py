@@ -21,7 +21,8 @@ from repro.kernels.edge_delta_apply.edge_delta_apply import (
 def bucket_slot_ops(delta: Delta, e: int, t_lo, t_hi, tile: int, cap: int,
                     forward: bool, slot0: int = 0,
                     n_valid_slots: int | None = None):
-    """Build the dense per-slot-tile op blocks i32[T, cap, 4].
+    """Build the dense per-slot-tile op blocks i32[T, 4, cap],
+    field-major: each entry is the column [local_slot, value, valid, 0].
 
     Every in-window edge op contributes ONE entry under its
     pre-resolved slot id (``delta.slot``, assigned host-side by the
@@ -69,9 +70,9 @@ def bucket_slot_ops(delta: Delta, e: int, t_lo, t_hi, tile: int, cap: int,
     entries = jnp.stack([ls[perm] % tile, val[perm],
                          jnp.ones_like(dst_p), jnp.zeros_like(dst_p)],
                         axis=1)
-    blocks = jnp.zeros((tcount + 1, cap, 4), jnp.int32)
+    blocks = jnp.zeros((tcount + 1, 4, cap), jnp.int32)
     keep = (tid_s < tcount) & (pos < cap)
-    blocks = blocks.at[jnp.where(keep, tid_s, tcount),
+    blocks = blocks.at[jnp.where(keep, tid_s, tcount), :,
                        dst_p].set(jnp.where(keep[:, None], entries, 0))
     return blocks[:tcount], overflow
 
@@ -79,7 +80,7 @@ def bucket_slot_ops(delta: Delta, e: int, t_lo, t_hi, tile: int, cap: int,
 def edge_delta_apply_slot_block(nodes: jnp.ndarray, emask_block: jnp.ndarray,
                                 delta: Delta, t_anchor: int, t_query: int,
                                 slot0: int, tile: int = 512,
-                                cap: int = 1024, interpret: bool = True):
+                                cap: int = 1024, interpret: bool = False):
     """Kernel-backed LWW reconstruction of one edge-mask *slot block*
     (shard-safe: this is what each device of a slot-sharded mesh runs).
 
@@ -109,7 +110,7 @@ def edge_delta_apply_slot_block(nodes: jnp.ndarray, emask_block: jnp.ndarray,
 
 def edge_delta_apply(anchor: EdgeGraph, delta: Delta, t_anchor: int,
                      t_query: int, tile: int = 512, cap: int = 1024,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """Kernel-backed reconstruct_at for EdgeGraph (edge mask on the
     Pallas slot kernel, node mask via XLA scatter).  Returns
     (EdgeGraph, overflow flag)."""

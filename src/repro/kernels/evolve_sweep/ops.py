@@ -42,7 +42,8 @@ import jax.numpy as jnp
 
 from repro.core.delta import ADD_EDGE, ADD_NODE, Delta
 from repro.core.graph import EdgeGraph
-from repro.core.queries import DEGREE_DIST_BINS, _degree_histogram
+from repro.core.queries import (DEGREE_DIST_BINS, _degree_histogram,
+                                avg_degree_of, density_of)
 from repro.core.reconstruct import reconstruct_dense, reconstruct_edge
 
 # Measures the incremental executor supports on BOTH layouts: pure
@@ -83,7 +84,7 @@ def sweep_nets(delta: Delta, t_lo, t_last, stride: int, num_buckets: int,
 def measure_from_state(measure: str, scope: str, v, deg, nodes_i, nn, ne):
     """The registered measure as a function of the swept integer state.
 
-    Expressions are verbatim from ``core.queries`` (both layouts share
+    The f32 finalizations are ``core.queries``'s own (both layouts share
     them there too) — this is what makes sweep samples bit-equal to
     point queries, f32 measures included.
     """
@@ -96,12 +97,9 @@ def measure_from_state(measure: str, scope: str, v, deg, nodes_i, nn, ne):
     if measure == "num_edges":
         return ne
     if measure == "density":
-        n = nn.astype(jnp.float32)
-        e = ne.astype(jnp.float32)
-        return jnp.where(n > 1, 2.0 * e / (n * (n - 1.0)), 0.0)
+        return density_of(nn, ne)
     if measure == "avg_degree":
-        n = jnp.maximum(nn, 1).astype(jnp.float32)
-        return 2.0 * ne.astype(jnp.float32) / n
+        return avg_degree_of(nn, ne)
     if measure == "degree_distribution":
         return _degree_histogram(deg, nodes_i.astype(bool), DEGREE_DIST_BINS)
     raise ValueError(f"measure {measure!r} is not sweepable")

@@ -8,10 +8,11 @@ instead of every unit:
   deg(v, t_lo + b·stride) = deg0(v) + Σ_{b' ≤ b} net[b', v]
 
 Grid: 1-D over node tiles.  ``bucket_sweep_events`` builds the same
-dense per-tile event blocks i32[T, cap, 4] ([local_node, sample, sign,
-valid]) as ``degree_series.ops.bucket_node_events``, but buckets by
-first-observing sample ceil((t − t_lo)/stride).  Kernel: scatter the
-per-(sample, node) nets into VMEM, then a forward running sum.
+field-major per-tile event blocks i32[T, 4, cap] (rows [local_node,
+sample, sign, valid]) as ``degree_series.ops.bucket_node_events``, but
+buckets by first-observing sample ceil((t − t_lo)/stride).  Kernel:
+scatter the per-(sample, node) nets into VMEM, then a forward running
+sum.
 
 This is the tiled specialization of the sweep executor for the
 node-degree measure; ``ops.batch_evolve`` is the general (all-measure,
@@ -28,6 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.delta import ADD_EDGE, Delta
+from repro.kernels.cell import padded_rows, update_cell
 
 
 @functools.partial(jax.jit,
@@ -35,10 +37,10 @@ from repro.core.delta import ADD_EDGE, Delta
                                     "cap"))
 def bucket_sweep_events(delta: Delta, n: int, t_lo, t_last, stride: int,
                         num_buckets: int, tile: int, cap: int):
-    """Dense per-node-tile sweep event blocks i32[T, cap, 4].
+    """Dense per-node-tile sweep event blocks i32[T, 4, cap].
 
     Each in-window edge op (t in (t_lo, t_last]) yields one event per
-    endpoint at sample ceil((t − t_lo)/stride); entries are
+    endpoint at sample ceil((t − t_lo)/stride); an entry is the column
     [local_node, sample, sign, valid]."""
     m = delta.capacity
     tcount = n // tile
@@ -62,8 +64,8 @@ def bucket_sweep_events(delta: Delta, n: int, t_lo, t_last, stride: int,
     keep = (tid_s < tcount) & (pos < cap)
     entries = jnp.stack([nodes[order] % tile, bs[order], signs[order],
                          jnp.ones_like(pos)], axis=1)
-    blocks = jnp.zeros((tcount + 1, cap, 4), jnp.int32)
-    blocks = blocks.at[jnp.where(keep, tid_s, tcount),
+    blocks = jnp.zeros((tcount + 1, 4, cap), jnp.int32)
+    blocks = blocks.at[jnp.where(keep, tid_s, tcount), :,
                        jnp.clip(pos, 0, cap - 1)].set(
         jnp.where(keep[:, None], entries, 0))
     return blocks[:tcount], overflow
@@ -74,24 +76,20 @@ def _kernel(ops_ref, deg_ref, out_ref, net_ref, *, cap: int,
     net_ref[...] = jnp.zeros_like(net_ref)
 
     def scatter(j, _):
-        ln = ops_ref[0, j, 0]
-        b = ops_ref[0, j, 1]
-        sign = ops_ref[0, j, 2]
-        valid = ops_ref[0, j, 3]
-        cur = pl.load(net_ref, (pl.ds(b, 1), pl.ds(ln, 1)))
-        pl.store(net_ref, (pl.ds(b, 1), pl.ds(ln, 1)),
-                 cur + jnp.where(valid > 0, sign, 0).reshape(1, 1))
+        @pl.when(ops_ref[0, 3, j] > 0)
+        def _():
+            sign = ops_ref[0, 2, j]
+            update_cell(net_ref, ops_ref[0, 1, j], ops_ref[0, 0, j],
+                        lambda w: w + sign)
         return 0
 
     jax.lax.fori_loop(0, cap, scatter, 0)
 
-    def fwd(b, acc):
+    # static row indices: Mosaic refuses dynamic single-row slices
+    acc = jnp.zeros_like(net_ref[0, :])
+    for b in range(num_buckets):
         acc = acc + net_ref[b, :]
         out_ref[b, :] = deg_ref[0, :] + acc
-        return acc
-
-    jax.lax.fori_loop(0, num_buckets, fwd,
-                      jnp.zeros_like(net_ref[0, :]), unroll=False)
 
 
 @functools.partial(jax.jit,
@@ -100,8 +98,8 @@ def _kernel(ops_ref, deg_ref, out_ref, net_ref, *, cap: int,
 def sweep_series_tiles(deg0: jax.Array, tile_ops: jax.Array,
                        tile: int = 256, cap: int = 1024,
                        num_buckets: int = 64,
-                       interpret: bool = True) -> jax.Array:
-    """deg0: i32[N]; tile_ops: i32[T, cap, 4] → i32[num_buckets, N]."""
+                       interpret: bool = False) -> jax.Array:
+    """deg0: i32[N]; tile_ops: i32[T, 4, cap] → i32[num_buckets, N]."""
     n = deg0.shape[0]
     assert n % tile == 0
     grid = (n // tile,)
@@ -109,19 +107,21 @@ def sweep_series_tiles(deg0: jax.Array, tile_ops: jax.Array,
         functools.partial(_kernel, cap=cap, num_buckets=num_buckets),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, cap, 4), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 4, cap), lambda i: (i, 0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((1, tile), lambda i: (0, i)),
         ],
         out_specs=pl.BlockSpec((num_buckets, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((num_buckets, n), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((num_buckets + 1, tile), jnp.int32)],
+        scratch_shapes=[pltpu.VMEM((padded_rows(num_buckets + 1), tile),
+                                   jnp.int32)],
         interpret=interpret,
     )(tile_ops, deg0.reshape(1, n))
 
 
 def sweep_degree_series(deg0: jax.Array, delta: Delta, t_lo, t_last,
                         stride: int, num_buckets: int, tile: int = 256,
-                        cap: int = 1024, interpret: bool = True):
+                        cap: int = 1024, interpret: bool = False):
     """i32[num_buckets, N]: every node's degree at each sweep sample.
 
     Row b holds deg(·, t_lo + b·stride); rows past the last real sample

@@ -135,11 +135,7 @@ def _local_moe(x_loc, wg, w_up, w_gate, w_down, *, cfg: ModelConfig,
     if ep_axes:
         idx = jnp.int32(0)
         for ax in ep_axes:
-            # jax.lax.axis_size is ≥ 0.5; psum(1) is the portable form
-            size = (jax.lax.axis_size(ax)
-                    if hasattr(jax.lax, "axis_size")
-                    else jax.lax.psum(1, ax))
-            idx = idx * size + jax.lax.axis_index(ax)
+            idx = idx * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         e0 = idx * e_loc
     else:
         e0 = jnp.int32(0)
@@ -177,11 +173,6 @@ def apply_moe_sharded(p: dict, x: jax.Array, cfg: ModelConfig):
     §Perf): tokens stay batch-sharded, expert weights stay EP/TP-sharded
     (never gathered), dispatch is shard-local, combine is one psum of
     [T_loc, d]."""
-    try:
-        shard_map = jax.shard_map
-    except AttributeError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
-
     from jax.sharding import PartitionSpec as P
     from repro.sharding import _mesh_axis_sizes, current_mesh, resolve
 
@@ -214,12 +205,8 @@ def apply_moe_sharded(p: dict, x: jax.Array, cfg: ModelConfig):
     fn = partial(_local_moe, cfg=cfg, e_loc=e_loc, ep_axes=ep,
                  red_axes=red)
     out_specs = P(dp if dp else None, None)
-    try:
-        sm = shard_map(fn, mesh=mesh, in_specs=in_specs,
+    sm = jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
-    except TypeError:  # jax ≤ 0.4 spells the flag check_rep
-        sm = shard_map(fn, mesh=mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_rep=False)
     out = sm(x.reshape(b * s, d), p["wg"], p["w_up"], w_gate, p["w_down"])
     return out.reshape(b, s, d)
 

@@ -35,13 +35,8 @@ LOGICAL_RULES: dict[str, tuple[str, ...]] = {
 
 def mesh_context(mesh):
     """Context manager putting ``mesh`` in scope for PartitionSpec
-    resolution (jax.set_mesh in jax ≥ 0.7, use_mesh in 0.5–0.6, the
-    plain ``Mesh`` context manager before that)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)  # pragma: no cover
-    return mesh  # jax ≤ 0.4: ``with mesh:`` sets thread_resources
+    resolution."""
+    return jax.set_mesh(mesh)
 
 
 @contextlib.contextmanager
@@ -56,27 +51,15 @@ def logical_rules(**over):
 
 
 def current_mesh():
-    """The mesh in scope: the abstract mesh on jax ≥ 0.5, the physical
-    thread-resources mesh (set by ``with mesh:``) before."""
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        return jax.sharding.get_abstract_mesh()
-    from jax._src.mesh import thread_resources
-    mesh = thread_resources.env.physical_mesh
-    return None if mesh is None or mesh.empty else mesh
+    """The (abstract) mesh in scope."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def _mesh_axis_sizes() -> dict[str, int]:
-    if hasattr(jax.sharding, "get_abstract_mesh"):
-        mesh = jax.sharding.get_abstract_mesh()
-        if mesh is None or not mesh.axis_names:
-            return {}
-        return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    # jax ≤ 0.4: the mesh context manager sets thread_resources instead
-    from jax._src.mesh import thread_resources
-    mesh = thread_resources.env.physical_mesh
-    if mesh is None or mesh.empty:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh is None or not mesh.axis_names:
         return {}
-    return dict(mesh.shape)
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def resolve(logical: str | None, dim: int | None = None,
@@ -181,9 +164,9 @@ def _path_str(path) -> str:
 
 
 def param_specs(params) -> dict:
-    """PartitionSpec pytree matching a param pytree (call inside a mesh
-    context — jax.sharding.use_mesh — so divisibility is checked against
-    the actual mesh)."""
+    """PartitionSpec pytree matching a param pytree (call inside
+    ``mesh_context`` so divisibility is checked against the actual
+    mesh)."""
     return jax.tree_util.tree_map_with_path(
         lambda path, leaf: param_spec_for(_path_str(path), leaf.shape),
         params)

@@ -274,7 +274,7 @@ def test_pallas_sweep_kernel_matches_scan():
     g0 = reconstruct_dense(s.current, d, t_cur, t_lo)
     series, overflow = sweep_degree_series(
         g0.degrees(), d, t_lo, t_lo + (nb - 1) * stride, stride, nb,
-        tile=4, cap=1024)
+        tile=4, cap=1024, interpret=True)
     assert not bool(overflow)
     for b in range(nb):
         t = min(t_lo + b * stride, t_cur)

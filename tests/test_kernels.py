@@ -1,5 +1,6 @@
 """Per-kernel correctness: shape/dtype sweeps vs the pure-jnp oracles
-(kernels run in interpret mode on CPU; TPU is the lowering target)."""
+(the graph kernels are passed interpret=True here, since the CPU has no
+Mosaic backend; tests/test_tpu_compile.py compiles them for a TPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,7 +24,7 @@ class TestDeltaApply:
         d = kstore.delta()
         for tq in [0, kstore.t_cur // 2, kstore.t_cur]:
             g, ovf = delta_apply(kstore.current, d, kstore.t_cur, tq,
-                                 tile=tile, cap=2048)
+                                 tile=tile, cap=2048, interpret=True)
             ref = delta_apply_ref(kstore.current, d, kstore.t_cur, tq)
             assert not bool(ovf)
             assert bool(jnp.all(g.adj == ref.adj)), (tile, tq)
@@ -35,7 +36,7 @@ class TestDeltaApply:
         t_a = 5
         anchor = delta_apply_ref(kstore.current, d, kstore.t_cur, t_a)
         g, ovf = delta_apply(anchor, d, t_a, kstore.t_cur, tile=64,
-                             cap=2048)
+                             cap=2048, interpret=True)
         assert not bool(ovf)
         assert bool(jnp.all(g.adj == kstore.current.adj))
 
@@ -44,7 +45,7 @@ class TestDeltaApply:
         d = kstore.delta()
         tq = kstore.t_cur // 3
         g, _ = delta_apply(kstore.current, d, kstore.t_cur, tq, tile=64,
-                           cap=2048)
+                           cap=2048, interpret=True)
         rr = reconstruct_dense(kstore.current, d, kstore.t_cur, tq)
         assert bool(jnp.all(g.adj == rr.adj))
 
@@ -52,7 +53,7 @@ class TestDeltaApply:
         from repro.kernels.delta_apply import delta_apply
         d = kstore.delta()
         _, ovf = delta_apply(kstore.current, d, kstore.t_cur, 0, tile=128,
-                             cap=8)
+                             cap=8, interpret=True)
         assert bool(ovf)
 
     @pytest.mark.parametrize("n_shards", [2, 4])
@@ -72,7 +73,7 @@ class TestDeltaApply:
                 nb, ab, ovf = delta_apply_row_block(
                     kstore.current.nodes[row0:row0 + rb],
                     kstore.current.adj[row0:row0 + rb], d, kstore.t_cur,
-                    tq, row0, tile=32, cap=2048)
+                    tq, row0, tile=32, cap=2048, interpret=True)
                 assert not bool(ovf)
                 nodes.append(nb)
                 adjs.append(ab)
@@ -99,7 +100,7 @@ class TestDeltaApply:
         blocks, ovf = bucket_ops(d50, 128, 0, k, 32, 8, True,
                                  n_rows=64, row0=0, n_valid_rows=48)
         assert not bool(ovf)
-        assert int(jnp.sum(blocks[..., 3])) == 0   # nothing bucketed
+        assert int(jnp.sum(blocks[..., 3, :])) == 0   # nothing bucketed
         # and the real-store non-uniform split stitches bit-exactly
         d = kstore.delta()
         tq = kstore.t_cur // 2
@@ -109,7 +110,7 @@ class TestDeltaApply:
             nb, ab, ovf = delta_apply_row_block(
                 kstore.current.nodes[row0:row0 + rcount],
                 kstore.current.adj[row0:row0 + rcount], d, kstore.t_cur,
-                tq, row0, tile=32, cap=2048)
+                tq, row0, tile=32, cap=2048, interpret=True)
             assert not bool(ovf), (row0, rcount)
             nodes.append(nb)
             adjs.append(ab)
@@ -130,7 +131,7 @@ class TestEdgeDeltaApply:
         cur = kstore.current_edge_snapshot()
         for tq in [0, kstore.t_cur // 2, kstore.t_cur]:
             g, ovf = edge_delta_apply(cur, d, kstore.t_cur, tq,
-                                      tile=tile, cap=2048)
+                                      tile=tile, cap=2048, interpret=True)
             ref = edge_delta_apply_ref(cur, d, kstore.t_cur, tq)
             assert not bool(ovf)
             assert bool(jnp.all(g.emask == ref.emask)), (tile, tq)
@@ -144,7 +145,7 @@ class TestEdgeDeltaApply:
         t_a = 5
         anchor = edge_delta_apply_ref(cur, d, kstore.t_cur, t_a)
         g, ovf = edge_delta_apply(anchor, d, t_a, kstore.t_cur, tile=64,
-                                  cap=2048)
+                                  cap=2048, interpret=True)
         assert not bool(ovf)
         assert bool(jnp.all(g.emask == cur.emask))
 
@@ -157,7 +158,7 @@ class TestEdgeDeltaApply:
         cur = kstore.current_edge_snapshot()
         tq = kstore.t_cur // 3
         g, _ = edge_delta_apply(cur, d, kstore.t_cur, tq, tile=64,
-                                cap=2048)
+                                cap=2048, interpret=True)
         rr = reconstruct_edge(cur, d, kstore.t_cur, tq)
         assert bool(jnp.all(g.emask == rr.emask))
         dense = reconstruct_dense(kstore.current, d, kstore.t_cur, tq)
@@ -169,7 +170,7 @@ class TestEdgeDeltaApply:
         d = kstore.delta()
         cur = kstore.current_edge_snapshot()
         _, ovf = edge_delta_apply(cur, d, kstore.t_cur, 0, tile=512,
-                                  cap=8)
+                                  cap=8, interpret=True)
         assert bool(ovf)
 
     @pytest.mark.parametrize("n_shards", [2, 4])
@@ -187,7 +188,7 @@ class TestEdgeDeltaApply:
             for slot0 in range(0, e, w):
                 nb, em, ovf = edge_delta_apply_slot_block(
                     cur.nodes, cur.emask[slot0:slot0 + w], d,
-                    kstore.t_cur, tq, slot0, tile=32, cap=2048)
+                    kstore.t_cur, tq, slot0, tile=32, cap=2048, interpret=True)
                 assert not bool(ovf)
                 masks.append(em)
                 assert bool(jnp.all(nb == ref.nodes))
@@ -214,7 +215,7 @@ class TestEdgeDeltaApply:
         blocks, ovf = bucket_slot_ops(d50, 64, 0, k, 32, 8, True,
                                       slot0=0, n_valid_slots=48)
         assert not bool(ovf)
-        assert int(jnp.sum(blocks[..., 2])) == 0   # nothing bucketed
+        assert int(jnp.sum(blocks[..., 2, :])) == 0   # nothing bucketed
         # and the real-store non-uniform split stitches bit-exactly
         d = kstore.delta()
         cur = kstore.current_edge_snapshot()
@@ -224,7 +225,7 @@ class TestEdgeDeltaApply:
         for slot0, scount in ((0, 48), (48, cur.e_cap - 48)):
             _, em, ovf = edge_delta_apply_slot_block(
                 cur.nodes, cur.emask[slot0:slot0 + scount], d,
-                kstore.t_cur, tq, slot0, tile=32, cap=2048)
+                kstore.t_cur, tq, slot0, tile=32, cap=2048, interpret=True)
             assert not bool(ovf), (slot0, scount)
             masks.append(em)
         assert bool(jnp.all(jnp.concatenate(masks) == ref.emask))
@@ -238,7 +239,7 @@ class TestDegreeSeries:
         d = kstore.delta()
         tk = kstore.t_cur // 3
         out, ovf = degree_series_kernel(kstore.current, d, tk, buckets,
-                                        tile=tile, cap=4096)
+                                        tile=tile, cap=4096, interpret=True)
         assert not bool(ovf)
         ref = degree_series_ref(kstore.current, d, tk, kstore.t_cur,
                                 buckets)
@@ -253,7 +254,7 @@ class TestDegreeSeries:
         tk = kstore.t_cur // 3
         buckets = 8
         full, ovf = degree_series_kernel(kstore.current, d, tk, buckets,
-                                         tile=32, cap=4096)
+                                         tile=32, cap=4096, interpret=True)
         assert not bool(ovf)
         deg = kstore.current.degrees()
         n = kstore.n_cap
@@ -261,7 +262,7 @@ class TestDegreeSeries:
         for row0 in range(0, n, n // 4):
             s, ovf = degree_series_rows(deg[row0:row0 + n // 4], d, tk,
                                         buckets, row0=row0, tile=32,
-                                        cap=4096)
+                                        cap=4096, interpret=True)
             assert not bool(ovf)
             parts.append(s)
         assert bool(jnp.all(jnp.concatenate(parts, axis=1) == full))

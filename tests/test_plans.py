@@ -107,3 +107,19 @@ def test_windowed_snapshot_matches(small_history):
         b = store.snapshot_at(t, windowed=True)
         assert np.array_equal(np.asarray(a.adj), np.asarray(b.adj)), t
         assert np.array_equal(np.asarray(a.adj), bf.adj(t)), t
+
+
+def test_div_rn_matches_ieee_division():
+    """The float measures' divide is the IEEE quotient on every backend
+    (a TPU's own f32 divide is only faithful)."""
+    import jax
+    from repro.core.queries import div_rn
+    rng = np.random.default_rng(0)
+    counts = rng.integers(0, 1 << 24, 20000).astype(np.float32)
+    dens = rng.integers(1, 1 << 24, 20000).astype(np.float32)
+    x = rng.uniform(1e-3, 1e6, 20000).astype(np.float32)
+    y = rng.uniform(1e-3, 1e6, 20000).astype(np.float32)
+    for a, b in ((counts, dens), (x, y), (dens, dens), (2 * dens, dens)):
+        got = np.asarray(jax.jit(div_rn)(jnp.asarray(a), jnp.asarray(b)))
+        assert np.array_equal(got.view(np.int32), (a / b).view(np.int32))
+    assert float(div_rn(jnp.float32(0), jnp.float32(3))) == 0.0

@@ -98,6 +98,21 @@ def test_global_measures_on_reconstruction(small_history):
     assert int(Q.triangle_count(g)) == tri
 
 
+def test_triangle_count_exact_past_f32_integers():
+    """6·triangles above 2^24 and path counts above 256: an f32
+    trace(A³) rounds here, and a bf16 second product would on a TPU."""
+    from repro.core import queries as Q
+    from repro.core.graph import DenseGraph
+    rng = np.random.default_rng(0)
+    adj = np.triu(rng.random((1024, 1024)) < 0.5, 1)
+    adj = adj | adj.T
+    a = adj.astype(np.float64)
+    exact = int(np.trace(a @ a @ a)) // 6
+    assert 6 * exact > 2 ** 24
+    g = DenseGraph(nodes=jnp.ones(1024, bool), adj=jnp.asarray(adj))
+    assert int(Q.triangle_count(g)) == exact
+
+
 def test_degree_distribution_and_pagerank(small_history):
     from repro.core import queries as Q
     store, bf = small_history
