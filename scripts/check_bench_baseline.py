@@ -55,13 +55,6 @@ BENCHES = {
     "replica": ("benchmarks/bench_replica.py",
                 "benchmarks/BENCH_replica.json",
                 ("smoke", "routed_qps")),
-    # metrics-ON serving throughput — a regression here means the
-    # observability layer started taxing the hot path (per-query
-    # registry ops, tracing left enabled, ...); the bench's own gate
-    # additionally enforces the on-vs-off overhead budget
-    "obs": ("benchmarks/bench_obs_overhead.py",
-            "benchmarks/BENCH_obs_overhead.json",
-            ("smoke", "qps_on")),
     # whole-repo static-analysis throughput — the lint gate runs on
     # every push, so a pass that goes accidentally quadratic (AST
     # re-walks per rule, call-closure fixpoint blowup) shows up here

@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core.plans import Query
 from repro.core.store import Op, TemporalGraphStore
+from repro.obs import compiles
 from repro.obs.metrics import default_registry
 from repro.obs.trace import (Tracer, active_tracer, install_tracer,
                              uninstall_tracer)
@@ -118,6 +119,9 @@ class GraphSession:
             max_pending=max_pending, overload=overload,
             shed_after_ms=shed_after_ms, metrics=self._metrics)
         self._publisher = None
+        # compiles (and compile-cache loads) counted into the registry
+        # while the session is open
+        self._compiles = compiles.subscribe(self._metrics)
         self._closed = False
 
     # ----------------------------------------------------------- lifecycle
@@ -142,6 +146,7 @@ class GraphSession:
             return
         self.frontend.stop()             # no-op unless start()ed
         self.live.close()
+        compiles.unsubscribe(self._compiles)
         self._closed = True
 
     def __enter__(self) -> "GraphSession":
@@ -263,8 +268,9 @@ class GraphSession:
     def enable_tracing(self, capacity: int = 16384) -> Tracer:
         """Install a process-wide span tracer (bounded ring).  One
         query then records plan → anchor-select → window-delta →
-        dispatch → measure; one swap records drain → WAL append/fsync
-        → seal → checkpoint → flip → publish."""
+        dispatch (→ compile) → fetch; one swap records drain → WAL
+        append/fsync → seal → checkpoint → flip → publish.  The spans
+        also appear in a ``jax.profiler`` trace taken meanwhile."""
         if self._tracer is None:
             self._tracer = install_tracer(Tracer(capacity=capacity))
         return self._tracer

@@ -62,7 +62,7 @@ from repro.core.segments import (SegmentedDeltaView,
                                  window_ops_count as _window_ops_host)
 from repro.obs import clock as _clock
 from repro.obs.metrics import COUNT_BUCKETS, default_registry
-from repro.obs.trace import trace_span
+from repro.obs.trace import NULL_SPAN, trace_span
 
 
 class WatermarkError(ValueError, RuntimeError):
@@ -407,14 +407,17 @@ def _measure_named(g, measure: str, scope: str, v):
     """Measure dispatch over both snapshot layouts: the edge-layout
     measures are segment reductions with the exact same integer counts
     and f32 finalizations as the dense ones, so layout never changes a
-    result bit (tests/test_engine.py edge-parity)."""
-    if isinstance(g, EdgeGraph):
+    result bit (tests/test_engine.py edge-parity).  Runs under the
+    ``measure`` name scope (metadata only), beside the reconstruction's
+    ``replay``."""
+    with jax.named_scope("measure"):
+        if isinstance(g, EdgeGraph):
+            if scope == "node":
+                return EDGE_NODE_MEASURES[measure](g, v)
+            return EDGE_GLOBAL_MEASURES[measure](g)
         if scope == "node":
-            return EDGE_NODE_MEASURES[measure](g, v)
-        return EDGE_GLOBAL_MEASURES[measure](g)
-    if scope == "node":
-        return NODE_MEASURES[measure](g, v)
-    return GLOBAL_MEASURES[measure](g)
+            return NODE_MEASURES[measure](g, v)
+        return GLOBAL_MEASURES[measure](g)
 
 
 @partial(jax.jit, static_argnames=("measure", "scope"))
@@ -1099,6 +1102,19 @@ class HistoricalQueryEngine:
             return self.delta
         return gather_window(self.delta, t_lo, t_hi, cap)
 
+    def _own_ops(self, kind: str, t_anchor: int, tks: np.ndarray,
+                 tls: np.ndarray) -> int:
+        """Logged ops a two-phase point or diff group's requests need:
+        the sum over requests of the ops in each of its own replay
+        windows (anchor → t for a point; anchor → t_l and t_l → t_k
+        for a diff), counted on the host, no device sync."""
+        def ops(a, b):
+            return _window_ops_host(self.t_host, min(a, b), max(a, b))
+        if kind == "point":
+            return sum(ops(t_anchor, int(t)) for t in tks)
+        return sum(ops(t_anchor, int(tl)) + ops(int(tl), int(tk))
+                   for tk, tl in zip(tks, tls))
+
     def _plan_delta(self, key: _GroupKey, tks: np.ndarray,
                     tls: np.ndarray, b: int) -> Delta:
         """The delta operand of one delta-only / hybrid group.  The
@@ -1144,7 +1160,7 @@ class HistoricalQueryEngine:
                                        force=(shard == "force"))
 
     def _run_group(self, key: _GroupKey, qs: list[Query], mesh=None,
-                   shard: str = "auto"):
+                   shard: str = "auto", span=NULL_SPAN):
         """Dispatch one group as a single device program; returns the
         (padded) device array — callers slice to len(qs) after one
         batch-wide ``device_get``, so group dispatches overlap.
@@ -1155,6 +1171,10 @@ class HistoricalQueryEngine:
         non-decomposable two-phase), adjacency rows for two-phase with
         psum-combinable measures.  Either way the padded device array
         that comes back holds bit-identical per-query values.
+
+        ``span`` is the caller's ``dispatch`` span: a real one gains
+        ``padded`` (the padded batch) and ``cap`` (the delta operand's
+        capacity), so a compile inside it names its shape.
         """
         b = len(qs)
         mode = self._shard_mode(key, b, mesh, shard)
@@ -1165,6 +1185,9 @@ class HistoricalQueryEngine:
                       if mode == "batch" else _pow2(b_floor))
         else:
             padded = _pow2(b_floor)
+        traced = span is not NULL_SPAN
+        if traced:
+            span.set(padded=padded)
         self.last_group_stats.append((key, b, mode))
         # per-group accounting: plan/layout/shard-mode labels come from
         # closed vocabularies (bounded label cardinality); batch size
@@ -1213,6 +1236,8 @@ class HistoricalQueryEngine:
         if key.plan in ("delta_only", "hybrid"):
             with trace_span("window_delta", plan=key.plan):
                 dlt = self._plan_delta(key, tks, tls, b)
+            if traced:
+                span.set(cap=dlt.capacity)
         else:
             dlt = None
         if mode == "batch":
@@ -1293,13 +1318,27 @@ class HistoricalQueryEngine:
                     t_anchor, g_anchor = self.selector.get(key.anchor_id)
             if key.kind == "evolve":
                 return self._run_evolve_group(key, b, mode, mesh, t_anchor,
-                                              g_anchor, tks, tls, vs_d)
+                                              g_anchor, tks, tls, vs_d,
+                                              span)
+            # replay fill, counted before the span so that it does not
+            # lengthen it: each of the ``padded`` requests replays all
+            # ``cap`` slots, ``replays`` times (a diff replays anchor →
+            # t_l, then t_l → t_k), and needs only ``own_ops`` of them
+            own_ops = (self._own_ops(key.kind, t_anchor, tks[:b], tls[:b])
+                       if traced and key.kind in ("point", "diff")
+                       else None)
             with trace_span("window_delta", plan="two_phase",
-                            anchor=key.anchor_id):
+                            anchor=key.anchor_id) as wd:
                 d = self._group_delta(
                     key, t_anchor,
                     np.concatenate([tks, tls])
                     if key.kind != "point" else tks)
+                if own_ops is not None:
+                    wd.set(cap=d.capacity, padded=padded,
+                           replays=1 if key.kind == "point" else 2,
+                           own_ops=own_ops)
+            if traced:
+                span.set(cap=d.capacity)
             nb = 0
             if key.kind == "agg":
                 nb = _pow2(max(int(tl - tk) + 1
@@ -1383,7 +1422,7 @@ class HistoricalQueryEngine:
 
     def _run_evolve_group(self, key: _GroupKey, b: int, mode, mesh,
                           t_anchor: int, g_anchor, tks: np.ndarray,
-                          tls: np.ndarray, vs_d):
+                          tls: np.ndarray, vs_d, span=NULL_SPAN):
         """Dispatch one sweep group as ONE device program
         (``kernels.evolve_sweep.batch_evolve``): reconstruct each
         query's start state from the shared anchor, then an incremental
@@ -1425,6 +1464,8 @@ class HistoricalQueryEngine:
             d_net = self.view.window_delta(lo_all, int(ts_last.max()))
         else:
             d_rec = d_net = self.delta
+        if span is not NULL_SPAN:
+            span.set(cap=d_rec.capacity)
         tlos_d = jnp.asarray(tks)
         widths_d = jnp.asarray(widths)
         if mode == "slots":
@@ -1545,15 +1586,17 @@ class HistoricalQueryEngine:
                 for key, idxs in groups.items():
                     with trace_span("dispatch", plan=key.plan,
                                     layout=key.layout,
-                                    measure=key.measure, batch=len(idxs)):
+                                    measure=key.measure,
+                                    batch=len(idxs)) as sp:
                         outs.append(
                             (idxs,
                              self._run_group(key,
                                              [queries[i] for i in idxs],
-                                             mesh=mesh, shard=shard)))
+                                             mesh=mesh, shard=shard,
+                                             span=sp)))
             finally:
                 self._stats_active = False
-            with trace_span("measure", groups=len(outs)):
+            with trace_span("fetch", groups=len(outs)):
                 fetched = jax.device_get([o for _, o in outs])
             results: list = [None] * len(queries)
             for (idxs, _), host in zip(outs, fetched):

@@ -56,12 +56,17 @@ def partial_reconstruct(current: DenseGraph, delta: Delta, t_cur, t_query,
     closure (other rows keep current values) — exactly the paper's
     contract: "it suffices to reconstruct the corresponding snapshots of
     the subgraph G'"."""
-    t_lo = jnp.minimum(t_cur, t_query)
-    t_hi = jnp.maximum(t_cur, t_query)
-    mask = closure_mask(current, delta, seed_mask, t_lo, t_hi, passes=passes)
+    # the closure and the final masking are replay work too
+    # (reconstruct_dense carries its own ``replay`` scope)
+    with jax.named_scope("replay"):
+        t_lo = jnp.minimum(t_cur, t_query)
+        t_hi = jnp.maximum(t_cur, t_query)
+        mask = closure_mask(current, delta, seed_mask, t_lo, t_hi,
+                            passes=passes)
     g = reconstruct_dense(current, delta, t_cur, t_query,
                           row_mask=mask, restrict_rows=True)
-    # Zero out rows outside the closure so accidental reads are loud.
-    adj = g.adj & mask[:, None] & mask[None, :]
-    nodes = g.nodes & mask
+    with jax.named_scope("replay"):
+        # Zero out rows outside the closure so accidental reads are loud.
+        adj = g.adj & mask[:, None] & mask[None, :]
+        nodes = g.nodes & mask
     return DenseGraph(nodes=nodes, adj=adj)

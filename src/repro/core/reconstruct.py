@@ -21,6 +21,11 @@ Three implementations of the paper's ForRec/BackRec (Algorithms 1 & 2):
 Both directions (Theorem 1) are supported; the direction is chosen from
 ``t_query`` vs ``t_anchor``.  Windows are half-open: SG_t contains the
 effect of every op with time ≤ t.
+
+The LWW reconstructions run under the ``replay`` name scope, with
+``scatter`` (the first/last op-index scatters) and ``decide``
+(``_lww_decide`` and the select) inside it: metadata only, which lets a
+device trace split a program's time between replay and measure.
 """
 from __future__ import annotations
 
@@ -58,6 +63,7 @@ def _lww_decide(first_idx, last_idx, op, forward, sentinel_hi, add_code):
 
 
 @partial(jax.jit, static_argnames=("restrict_rows",))
+@jax.named_scope("replay")
 def reconstruct_dense(anchor: DenseGraph, delta: Delta, t_anchor, t_query,
                       row_mask: jax.Array | None = None,
                       restrict_rows: bool = False) -> DenseGraph:
@@ -86,28 +92,34 @@ def reconstruct_dense(anchor: DenseGraph, delta: Delta, t_anchor, t_query,
     e_win = in_win & delta.is_edge_op()
     e_first = jnp.where(e_win, idx, m)
     e_last = jnp.where(e_win, idx, -1)
-    first = jnp.full((n, n), m, jnp.int32)
-    last = jnp.full((n, n), -1, jnp.int32)
-    first = first.at[delta.u, delta.v].min(e_first)
-    first = first.at[delta.v, delta.u].min(e_first)
-    last = last.at[delta.u, delta.v].max(e_last)
-    last = last.at[delta.v, delta.u].max(e_last)
-    decided, value = _lww_decide(first, last, delta.op, forward, m, ADD_EDGE)
-    adj = jnp.where(decided, value, anchor.adj)
+    with jax.named_scope("scatter"):
+        first = jnp.full((n, n), m, jnp.int32)
+        last = jnp.full((n, n), -1, jnp.int32)
+        first = first.at[delta.u, delta.v].min(e_first)
+        first = first.at[delta.v, delta.u].min(e_first)
+        last = last.at[delta.u, delta.v].max(e_last)
+        last = last.at[delta.v, delta.u].max(e_last)
+    with jax.named_scope("decide"):
+        decided, value = _lww_decide(first, last, delta.op, forward, m,
+                                     ADD_EDGE)
+        adj = jnp.where(decided, value, anchor.adj)
 
     # ---- nodes ----
     n_win = in_win & delta.is_node_op()
     n_first = jnp.where(n_win, idx, m)
     n_last = jnp.where(n_win, idx, -1)
-    firstn = jnp.full((n,), m, jnp.int32).at[delta.u].min(n_first)
-    lastn = jnp.full((n,), -1, jnp.int32).at[delta.u].max(n_last)
-    decided_n, value_n = _lww_decide(firstn, lastn, delta.op, forward, m,
-                                     ADD_NODE)
-    nodes = jnp.where(decided_n, value_n, anchor.nodes)
+    with jax.named_scope("scatter"):
+        firstn = jnp.full((n,), m, jnp.int32).at[delta.u].min(n_first)
+        lastn = jnp.full((n,), -1, jnp.int32).at[delta.u].max(n_last)
+    with jax.named_scope("decide"):
+        decided_n, value_n = _lww_decide(firstn, lastn, delta.op, forward,
+                                         m, ADD_NODE)
+        nodes = jnp.where(decided_n, value_n, anchor.nodes)
     return DenseGraph(nodes=nodes, adj=adj)
 
 
 @jax.jit
+@jax.named_scope("replay")
 def reconstruct_edge(anchor: EdgeGraph, delta: Delta, t_anchor,
                      t_query) -> EdgeGraph:
     """Last-writer-wins reconstruction on the edge-slot layout.
@@ -124,21 +136,26 @@ def reconstruct_edge(anchor: EdgeGraph, delta: Delta, t_anchor,
     idx = jnp.arange(m, dtype=jnp.int32)
 
     e_win = in_win & delta.is_edge_op()
-    first = jnp.full((anchor.e_cap,), m, jnp.int32)
-    last = jnp.full((anchor.e_cap,), -1, jnp.int32)
-    first = first.at[delta.slot].min(jnp.where(e_win, idx, m))
-    last = last.at[delta.slot].max(jnp.where(e_win, idx, -1))
-    decided, value = _lww_decide(first, last, delta.op, forward, m, ADD_EDGE)
-    emask = jnp.where(decided, value, anchor.emask)
+    with jax.named_scope("scatter"):
+        first = jnp.full((anchor.e_cap,), m, jnp.int32)
+        last = jnp.full((anchor.e_cap,), -1, jnp.int32)
+        first = first.at[delta.slot].min(jnp.where(e_win, idx, m))
+        last = last.at[delta.slot].max(jnp.where(e_win, idx, -1))
+    with jax.named_scope("decide"):
+        decided, value = _lww_decide(first, last, delta.op, forward, m,
+                                     ADD_EDGE)
+        emask = jnp.where(decided, value, anchor.emask)
 
     n_win = in_win & delta.is_node_op()
-    firstn = jnp.full((anchor.n_cap,), m, jnp.int32)
-    lastn = jnp.full((anchor.n_cap,), -1, jnp.int32)
-    firstn = firstn.at[delta.slot].min(jnp.where(n_win, idx, m))
-    lastn = lastn.at[delta.slot].max(jnp.where(n_win, idx, -1))
-    decided_n, value_n = _lww_decide(firstn, lastn, delta.op, forward, m,
-                                     ADD_NODE)
-    nodes = jnp.where(decided_n, value_n, anchor.nodes)
+    with jax.named_scope("scatter"):
+        firstn = jnp.full((anchor.n_cap,), m, jnp.int32)
+        lastn = jnp.full((anchor.n_cap,), -1, jnp.int32)
+        firstn = firstn.at[delta.slot].min(jnp.where(n_win, idx, m))
+        lastn = lastn.at[delta.slot].max(jnp.where(n_win, idx, -1))
+    with jax.named_scope("decide"):
+        decided_n, value_n = _lww_decide(firstn, lastn, delta.op, forward,
+                                         m, ADD_NODE)
+        nodes = jnp.where(decided_n, value_n, anchor.nodes)
     return dataclasses.replace(anchor, nodes=nodes, emask=emask)
 
 

@@ -111,8 +111,11 @@ def sweep_scan(measure: str, scope: str, v, deg0, nodes0, nn0, ne0, nets):
     def step(carry, net):
         deg, nod, nn, ne = carry
         deg_net, node_net, ne_net, nn_net = net
-        carry = (deg + deg_net, nod + node_net, nn + nn_net, ne + ne_net)
-        out = measure_from_state(measure, scope, v, *carry)
+        with jax.named_scope("replay"):
+            carry = (deg + deg_net, nod + node_net, nn + nn_net,
+                     ne + ne_net)
+        with jax.named_scope("measure"):
+            out = measure_from_state(measure, scope, v, *carry)
         return carry, out
 
     _, outs = jax.lax.scan(step, (deg0, nodes0.astype(jnp.int32),
@@ -136,6 +139,10 @@ def batch_evolve(anchor, d_rec: Delta, d_net: Delta, t_anchor,
     Output: [Q, num_buckets] (i32 or f32 per measure), or
     [Q, num_buckets, bins] for degree_distribution.  Samples past a
     query's width repeat its last state — callers slice ``[:width]``.
+
+    Name scopes (metadata only): the start-state reconstruction, the
+    NET scatter and the scan's apply run under ``replay``, the scan's
+    measure under ``measure``.
     """
     n_cap = anchor.n_cap
     edge_layout = isinstance(anchor, EdgeGraph)
@@ -146,7 +153,9 @@ def batch_evolve(anchor, d_rec: Delta, d_net: Delta, t_anchor,
         else:
             g = reconstruct_dense(anchor, d_rec, t_anchor, t_lo)
         t_last = t_lo + (width - 1) * stride
-        nets = sweep_nets(d_net, t_lo, t_last, stride, num_buckets, n_cap)
+        with jax.named_scope("replay"):
+            nets = sweep_nets(d_net, t_lo, t_last, stride, num_buckets,
+                              n_cap)
         return sweep_scan(measure, scope, v, g.degrees(), g.nodes,
                           g.num_nodes(), g.num_edges(), nets)
 

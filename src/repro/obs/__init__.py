@@ -1,6 +1,6 @@
 """``repro.obs`` — zero-dependency observability for the whole stack.
 
-Three pieces, one import surface:
+The pieces, one import surface:
 
 * ``metrics`` — thread-safe ``MetricsRegistry`` (counters, gauges,
   log-bucket histograms) with Prometheus text exposition and a JSON
@@ -10,6 +10,8 @@ Three pieces, one import surface:
   buffer with Chrome ``trace_event`` export; free when disabled.
 * ``slowlog`` — threshold-triggered slow-query records with full plan
   attribution.
+* ``compiles`` — one process-wide JAX compile listener feeding every
+  open session's registry (and ``compile`` spans when tracing).
 
 See README "Observability" for the metrics catalog and quickstarts.
 """

@@ -386,9 +386,9 @@ class MetricsRegistry:
 
 
 class NullRegistry(MetricsRegistry):
-    """A registry whose children do nothing: ``metrics off`` for the
-    overhead benchmark and for callers that want the instrumented code
-    paths with zero accounting cost.  Snapshots are empty."""
+    """A registry whose children do nothing: ``metrics off`` for
+    callers that want the instrumented code paths with zero accounting
+    cost.  Snapshots are empty."""
 
     def __init__(self):
         super().__init__(parent=None)

@@ -79,10 +79,9 @@ class FrontendStats:
     ``sync`` (when given) runs before every read: the frontend's
     submit path accumulates its per-request counts as plain ints under
     the queue lock it already holds (registry child ops per submit
-    would be measurable overhead on the serving hot path — the
-    bench_obs_overhead contract) and folds them into the registry at
-    every drain; the sync hook folds them on read too, so the view
-    stays exact at all times.
+    would be measurable overhead on the serving hot path) and folds
+    them into the registry at every drain; the sync hook folds them on
+    read too, so the view stays exact at all times.
     """
 
     _COUNTERS = {

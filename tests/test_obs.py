@@ -494,3 +494,153 @@ def test_frontend_and_replica_stats_are_registry_views(tmp_path):
         snap = reg.snapshot()["counters"]
         assert sum(snap["frontend_submitted_total"].values()) == 2
         assert sum(snap["frontend_cache_hits_total"].values()) == 1
+
+
+# ---------------------------------------------------------------------------
+# tracing from inside the device programs (ISSUE 13)
+# ---------------------------------------------------------------------------
+
+def test_tracing_off_again_returns_the_null_span():
+    """A tracer that came and went leaves tracing free again: the span
+    sites get the shared singleton, no annotation, no clock read."""
+    install_tracer(Tracer())
+    with trace_span("on") as sp:
+        assert sp is not NULL_SPAN
+    uninstall_tracer()
+    assert trace_span("off", a=1) is NULL_SPAN
+
+
+def test_span_start_matches_its_profiler_annotation(tmp_path):
+    """A span's ``ts`` is on the clock the JAX profiler stamps host
+    events with, and each span opens a ``TraceAnnotation`` of its name:
+    in a CPU profile the two starts agree to within 1 ms."""
+    import glob
+    import jax
+    tr = install_tracer(Tracer())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(3):
+            with trace_span("obs.aligned"):
+                jax.numpy.ones(4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    origin = None
+    starts = []
+    for plane in data.planes:
+        origin = dict(plane.stats).get("profile_start_time", origin)
+        for line in plane.lines:
+            starts += [e.start_ns for e in line.events
+                       if e.name == "obs.aligned"]
+    assert origin is not None and len(starts) == 3
+    ring = sorted(e["ts"] * 1e3 for e in tr.events()
+                  if e["name"] == "obs.aligned")
+    for ann, span in zip(sorted(starts), ring):
+        assert abs(span - (origin + ann)) < 1e6
+
+
+def test_compile_listener_counts_names_and_spans_a_compile():
+    """One forced compile: counted and named in every subscribed
+    registry, timed in ``jax_compile_seconds``, and recorded as a
+    ``compile`` span nested in the span that triggered it; a closed
+    session's registry stops counting."""
+    import jax
+    import jax.numpy as jnp
+    from repro.api import GraphSession
+
+    reg = MetricsRegistry()
+    sess = GraphSession(n_cap=8, metrics=reg)
+    closed = MetricsRegistry()
+    GraphSession(n_cap=8, metrics=closed).close()
+    assert closed.get("jax_compile_seconds")["count"] == 0
+    tr = install_tracer(Tracer())
+    try:
+        def forced_compile(x):
+            return x * 3 + 1
+        with trace_span("dispatch"):
+            jax.jit(forced_compile)(jnp.ones((7, 13))).block_until_ready()
+    finally:
+        sess.close()
+    counts = reg.snapshot()["counters"]["jax_compiles_total"]
+    assert counts.get("program=jit(forced_compile)") == 1
+    hist = reg.get("jax_compile_seconds")
+    assert hist["count"] == sum(counts.values()) and hist["sum"] > 0
+    assert closed.get("jax_compile_seconds")["count"] == 0
+    assert "jax_compiles_total" not in closed.snapshot()["counters"]
+    evs = tr.events()
+    (comp,) = [e for e in evs if e["name"] == "compile"
+               and e["args"]["program"] == "jit(forced_compile)"]
+    (outer,) = [e for e in evs if e["name"] == "dispatch"]
+    assert comp["args"]["seconds"] > 0
+    assert comp["dur"] == pytest.approx(comp["args"]["seconds"] * 1e6)
+    assert outer["ts"] <= comp["ts"]
+    assert comp["ts"] + comp["dur"] <= outer["ts"] + outer["dur"] + 1.0
+
+
+def test_point_program_hlo_carries_replay_and_measure_scopes():
+    """The compiled dense point program names its ops by scope:
+    ``replay/scatter``, ``replay/decide`` and ``measure`` (JAX writes
+    a scope opened under ``vmap`` as ``vmap(measure)``)."""
+    import re
+    import jax.numpy as jnp
+    from repro.core.delta import Delta
+    from repro.core.engine import batch_two_phase_point
+    from repro.core.graph import DenseGraph
+
+    n, m = 16, 64
+    g = DenseGraph(nodes=jnp.zeros((n,), bool),
+                   adj=jnp.zeros((n, n), bool))
+    z = jnp.zeros((m,), jnp.int32)
+    d = Delta(op=z, u=z, v=z, slot=z, t=z, n_ops=jnp.int32(0))
+    ts = jnp.zeros((2,), jnp.int32)
+    text = batch_two_phase_point.lower(
+        g, d, 0, ts, ts, measure="triangles",
+        scope="global").compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    assert any("/replay/scatter/" in n for n in names)
+    assert any("/replay/decide/" in n for n in names)
+    assert any(re.search(r"/(vmap\()?measure\)?/", n) for n in names)
+
+
+def test_traced_evaluate_many_emits_fetch_and_replay_fill():
+    """A traced call fetches under ``fetch`` (no ``measure`` span);
+    two-phase point and diff ``window_delta`` spans carry the replay
+    fill, ``dispatch`` spans the padded batch and delta capacity."""
+    import numpy as np
+    from repro.core import Query
+    from repro.core.generate import (EvolutionParams, build_store,
+                                     generate_ops)
+
+    params = EvolutionParams(m_attach=3, lam_extra=1.0, lam_remove=1.0)
+    store = build_store(60, params, seed=1)
+    times = np.sort([o.t for o in generate_ops(60, params, seed=1)])
+    t_cur = store.t_cur
+    qs = [Query(kind="point", scope="global", measure="num_edges",
+                t_k=t_cur // 3),
+          Query(kind="diff", scope="global", measure="num_edges",
+                t_k=t_cur // 4, t_l=t_cur // 2),
+          Query(kind="diff", scope="global", measure="num_edges",
+                t_k=t_cur // 5, t_l=t_cur // 3)]
+    tr = install_tracer(Tracer())
+    store.engine().evaluate_many(qs, plan="two_phase")
+    evs = tr.events()
+    names = {e["name"] for e in evs}
+    assert "fetch" in names and "measure" not in names
+    fills = [e["args"] for e in evs if e["name"] == "window_delta"
+             and "own_ops" in e["args"]]
+    assert sorted(a["replays"] for a in fills) == [1, 2]
+    for a in fills:
+        assert 0 < a["own_ops"] <= a["cap"] * a["padded"] * a["replays"]
+    (point,) = [a for a in fills if a["replays"] == 1]
+    # the one point request replays (its time, the current state]
+    assert point["own_ops"] == int(np.sum((times > t_cur // 3)
+                                          & (times <= t_cur)))
+    assert point["padded"] == 1
+    (diff,) = [a for a in fills if a["replays"] == 2]
+    assert diff["padded"] == 2
+    for e in evs:
+        if e["name"] == "dispatch":
+            assert e["args"]["padded"] >= e["args"]["batch"]
+            assert e["args"]["cap"] >= 1
