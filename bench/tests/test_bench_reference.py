@@ -1,7 +1,10 @@
-"""The plain reference on a hand-built history."""
+"""The plain reference on a hand-built history; its triangle series
+against the bitset formula it replaced."""
 import numpy as np
+import pytest
 
 import benchkit  # noqa: F401  (puts the benchmark on sys.path)
+from harness import datagen
 from harness.traffic import Req
 
 import importlib.util
@@ -82,3 +85,91 @@ def test_same_is_exact():
     assert not replay.same(np.float32(2.5), np.nextafter(np.float32(2.5),
                                                          np.float32(3)))
     assert not replay.same(np.arange(3), np.arange(4))
+
+
+# --- the triangle series against the bitset formula --------------------
+
+
+def triangles_by_matrix(cols, n_cap: int, t: int) -> int:
+    """Triangles of the live-edge graph at ``t`` (an edge is live iff the
+    last op on its key at or before ``t`` is an insert), counted by the
+    bitset formula: common neighbours of both ends of every live edge,
+    over three."""
+    op, u, v, tt = cols
+    m = (tt <= t) & (op >= A_E)
+    key = (np.minimum(u, v) * n_cap + np.maximum(u, v))[m][::-1]
+    keys, last = np.unique(key, return_index=True)
+    live = keys[op[m][::-1][last] == A_E]
+    ku, kv = live // n_cap, live % n_cap
+    adj = np.zeros((n_cap, n_cap), bool)
+    adj[ku, kv] = adj[kv, ku] = True
+    rows = np.packbits(adj, axis=1)
+    common = np.unpackbits(rows[ku] & rows[kv], axis=1).sum()
+    return int(common) // 3
+
+
+def random_history(seed: int, n: int = 12, steps: int = 400) -> np.ndarray:
+    """Edge inserts and removals on a few nodes, the same keys removed and
+    re-added many times, several ops per time, either end order, and some
+    ops that change nothing (an insert of a live key, a removal of a dead
+    one)."""
+    rng = np.random.default_rng(seed)
+    rows = [(A_N, i, i, 0) for i in range(n)]
+    for s in range(steps):
+        a, b = rng.choice(n, 2, replace=False)
+        rows.append((int(rng.choice([A_E, A_E, R_E])), int(a), int(b),
+                     1 + s // 3))
+    return np.asarray(rows, np.int64).T
+
+
+def _points(ts):
+    return [q("point", "triangles", int(t)) for t in ts]
+
+
+def test_triangle_series_matches_matrix_on_history():
+    cols = np.asarray(HISTORY, np.int64).T
+    ts = range(0, 7)
+    want = [triangles_by_matrix(cols, 8, t) for t in ts]
+    assert want == [0, 1, 1, 0, 2, 1, 1]
+    assert ref().answers(_points(ts)) == want
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3, 4, 5, 6])
+def test_triangle_series_matches_matrix_on_random_histories(seed):
+    cols = random_history(seed)
+    ts = range(0, int(cols[3].max()) + 2)
+    r = replay.Reference(cols, 16)
+    want = [triangles_by_matrix(cols, 16, t) for t in ts]
+    assert max(want) > 0 and r.answers(_points(ts)) == want
+
+
+@pytest.fixture(scope="module")
+def table3():
+    """The configuration's history at seed 7, and its ``n_cap``."""
+    cfg = benchkit.load_json("bench/configs/table3-dense-n5063.json")
+    cols = datagen.generate(datagen.Model.from_config(cfg["data"]), seed=7)
+    return cols, cfg["session"]["n_cap"]
+
+
+def test_triangle_series_matches_matrix_on_table3(table3):
+    cols, n_cap = table3
+    ts = np.random.default_rng(7).integers(0, cols[3].max() + 1, 16)
+    got = replay.Reference(cols, n_cap).answers(_points(ts))
+    assert got == [triangles_by_matrix(cols, n_cap, t) for t in ts]
+
+
+def test_triangles_at_every_unit_of_table3(table3):
+    """One ``answers`` call over every unit of the history: the check's
+    cost must not grow with the number of times asked."""
+    cols, n_cap = table3
+    t_max = int(cols[3].max())
+    got = replay.Reference(cols, n_cap).answers(_points(range(t_max + 1)))
+    assert len(got) == t_max + 1
+    for t in np.random.default_rng(8).integers(0, t_max + 1, 16):
+        assert got[t] == triangles_by_matrix(cols, n_cap, t)
+
+
+def test_self_loop_key_refused():
+    cols = np.asarray(HISTORY + [(A_E, 2, 2, 6)], np.int64).T
+    with pytest.raises(ValueError, match="self-loop"):
+        replay.Reference(cols, 8).answers(_points([6]))
