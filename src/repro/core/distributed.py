@@ -38,11 +38,11 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.delta import ADD_EDGE, Delta
+from repro.core.delta import ADD_EDGE, ADD_NODE, Delta
 from repro.core.graph import DenseGraph, EdgeGraph
 from repro.core.plans import masked_aggregate
 from repro.core.queries import avg_degree_of, density_of
-from repro.core.reconstruct import _lww_decide
+from repro.core.reconstruct import _lww_decide, _lww_key
 from repro.sharding.graph import (AXIS, batch_specs,  # noqa: F401
                                   graph_mesh, replicate, shard_rows,
                                   shard_slots)
@@ -303,42 +303,31 @@ def _slot_lww(emask_l, delta: Delta, t_anchor, t_query, slot0):
     """Shard-local last-writer-wins over the local slot block (ops are
     pre-resolved to slot ids host-side, so this is a 1-D scatter)."""
     e_loc = emask_l.shape[0]
-    m = delta.capacity
     forward = t_query >= t_anchor
     t_lo = jnp.minimum(t_anchor, t_query)
     t_hi = jnp.maximum(t_anchor, t_query)
     in_win = delta.window_mask(t_lo, t_hi) & delta.valid_mask()
-    idx = jnp.arange(m, dtype=jnp.int32)
 
-    ew = in_win & delta.is_edge_op()
     ls = delta.slot - slot0
-    ok = ew & (ls >= 0) & (ls < e_loc)
+    ok = in_win & delta.is_edge_op() & (ls >= 0) & (ls < e_loc)
     ls = jnp.clip(ls, 0, e_loc - 1)
-    first = jnp.full((e_loc,), m, jnp.int32).at[ls].min(
-        jnp.where(ok, idx, m))
-    last = jnp.full((e_loc,), -1, jnp.int32).at[ls].max(
-        jnp.where(ok, idx, -1))
-    dec, val = _lww_decide(first, last, delta.op, forward, m, ADD_EDGE)
-    return jnp.where(dec, val, emask_l)
+    key = jnp.full((e_loc,), -1, jnp.int32).at[ls].max(
+        _lww_key(ok, delta.op, forward, ADD_EDGE))
+    return _lww_decide(key, emask_l)
 
 
 def _node_lww(nodes, delta: Delta, t_anchor, t_query):
     """Full-N node-mask LWW (the node mask is replicated on every
     shard — it is N-sized, negligible next to the slot scatter)."""
     n = nodes.shape[0]
-    m = delta.capacity
     forward = t_query >= t_anchor
     t_lo = jnp.minimum(t_anchor, t_query)
     t_hi = jnp.maximum(t_anchor, t_query)
     in_win = delta.window_mask(t_lo, t_hi) & delta.valid_mask()
-    idx = jnp.arange(m, dtype=jnp.int32)
-    nw = in_win & delta.is_node_op()
-    firstn = jnp.full((n,), m, jnp.int32).at[delta.u].min(
-        jnp.where(nw, idx, m))
-    lastn = jnp.full((n,), -1, jnp.int32).at[delta.u].max(
-        jnp.where(nw, idx, -1))
-    dec_n, val_n = _lww_decide(firstn, lastn, delta.op, forward, m, 0)
-    return jnp.where(dec_n, val_n, nodes)
+    keyn = jnp.full((n,), -1, jnp.int32).at[delta.u].max(
+        _lww_key(in_win & delta.is_node_op(), delta.op, forward,
+                 ADD_NODE))
+    return _lww_decide(keyn, nodes)
 
 
 def _two_phase_slots_local(nodes, eu_l, ev_l, emask_l, n_reg, delta,
@@ -469,37 +458,28 @@ def _evolve_slots_local(nodes, eu_l, ev_l, emask_l, n_reg, d_rec, d_net,
 def _local_lww(nodes_l, adj_l, delta: Delta, t_anchor, t_query):
     """Shard-local last-writer-wins over the local row block."""
     n_loc = adj_l.shape[0]
-    m = delta.capacity
     row0 = jax.lax.axis_index(AXIS) * n_loc
     forward = t_query >= t_anchor
     t_lo = jnp.minimum(t_anchor, t_query)
     t_hi = jnp.maximum(t_anchor, t_query)
     in_win = delta.window_mask(t_lo, t_hi) & delta.valid_mask()
-    idx = jnp.arange(m, dtype=jnp.int32)
 
     # Edge op (u, v) lands in local row u (col v) and local row v (col u).
     e_win = in_win & delta.is_edge_op()
-    first = jnp.full((n_loc, adj_l.shape[1]), m, jnp.int32)
-    last = jnp.full((n_loc, adj_l.shape[1]), -1, jnp.int32)
+    key = jnp.full((n_loc, adj_l.shape[1]), -1, jnp.int32)
     for (r, c) in ((delta.u, delta.v), (delta.v, delta.u)):
         lr = r - row0
         ok = e_win & (lr >= 0) & (lr < n_loc)
         lr = jnp.clip(lr, 0, n_loc - 1)
-        first = first.at[lr, c].min(jnp.where(ok, idx, m))
-        last = last.at[lr, c].max(jnp.where(ok, idx, -1))
-    dec, val = _lww_decide(first, last, delta.op, forward, m, ADD_EDGE)
-    adj_l = jnp.where(dec, val, adj_l)
+        key = key.at[lr, c].max(_lww_key(ok, delta.op, forward, ADD_EDGE))
+    adj_l = _lww_decide(key, adj_l)
 
-    n_win = in_win & delta.is_node_op()
     ln = delta.u - row0
-    ok = n_win & (ln >= 0) & (ln < n_loc)
+    ok = in_win & delta.is_node_op() & (ln >= 0) & (ln < n_loc)
     ln = jnp.clip(ln, 0, n_loc - 1)
-    firstn = jnp.full((n_loc,), m, jnp.int32).at[ln].min(
-        jnp.where(ok, idx, m))
-    lastn = jnp.full((n_loc,), -1, jnp.int32).at[ln].max(
-        jnp.where(ok, idx, -1))
-    dec_n, val_n = _lww_decide(firstn, lastn, delta.op, forward, m, 0)
-    nodes_l = jnp.where(dec_n, val_n, nodes_l)
+    keyn = jnp.full((n_loc,), -1, jnp.int32).at[ln].max(
+        _lww_key(ok, delta.op, forward, ADD_NODE))
+    nodes_l = _lww_decide(keyn, nodes_l)
     return nodes_l, adj_l
 
 

@@ -10,9 +10,11 @@ Three implementations of the paper's ForRec/BackRec (Algorithms 1 & 2):
 2. ``reconstruct_at`` — the TPU-native *last-writer-wins* reduction
    (DESIGN.md §2.2).  Validity of a key at t′ is decided by the last op
    with t ≤ t′ (forward from an anchor) or the first op with t > t′
-   (backward): a scatter-argmin/argmax over op indices, fully parallel
-   over ops — no sequential dependence.  This is the beyond-paper
-   optimization measured against (1) in EXPERIMENTS.md §Perf.
+   (backward): one scatter-max of a per-op key that carries the op's
+   log position and the key's new bit (``_lww_key``), fully parallel
+   over ops — no sequential dependence, and the op column is never
+   read back per key.  This is the beyond-paper optimization measured
+   against (1) in EXPERIMENTS.md §Perf.
 
 3. ``validity_series`` — all-times reconstruction for range queries:
    per-time-bucket net counts + a cumulative correction, one pass over
@@ -23,9 +25,10 @@ Both directions (Theorem 1) are supported; the direction is chosen from
 effect of every op with time ≤ t.
 
 The LWW reconstructions run under the ``replay`` name scope, with
-``scatter`` (the first/last op-index scatters) and ``decide``
-(``_lww_decide`` and the select) inside it: metadata only, which lets a
-device trace split a program's time between replay and measure.
+``scatter`` (the key scatter-max) and ``decide`` (``_lww_decide``, the
+read of the scattered key and the select) inside it: metadata only,
+which lets a device trace split a program's time between replay and
+measure.
 """
 from __future__ import annotations
 
@@ -44,22 +47,29 @@ from repro.core.graph import DenseGraph, EdgeGraph
 # --------------------------------------------------------------------------
 
 
-def _lww_decide(first_idx, last_idx, op, forward, sentinel_hi, add_code):
-    """Shared decision rule.
+def _lww_key(win, op, forward, add_code):
+    """Per-op last-writer-wins key, −1 where ``win`` is False.
 
-    forward:  decided by LAST in-window op; new value = (op == ADD).
-    backward: decided by FIRST in-window op; new value = (op == REM),
-              i.e. if the first later op re-adds the key it was absent
-              at t′, if it removes the key it was present.
-    Returns (decided_mask, new_value).
+    forward:  ``2·idx + (op == add_code)`` — the scatter-max per key
+              picks the LAST in-window op, and bit 0 is the new value;
+    backward: ``2·(m − 1 − idx) + (op != add_code)`` — the max picks
+              the FIRST in-window op: if it re-adds the key, the key
+              was absent at t′; if it removes the key, it was present.
+    The largest key is 2·m − 1, so int32 holds it for any m ≤ 2^30.
     """
-    dec_f = last_idx >= 0
-    val_f = op[jnp.clip(last_idx, 0)] == add_code
-    dec_b = first_idx < sentinel_hi
-    val_b = op[jnp.clip(first_idx, None, sentinel_hi - 1)] != add_code
-    decided = jnp.where(forward, dec_f, dec_b)
-    value = jnp.where(forward, val_f, val_b)
-    return decided, value
+    m = op.shape[0]
+    idx = jnp.arange(m, dtype=jnp.int32)
+    is_add = op == add_code
+    pos = jnp.where(forward, idx, m - 1 - idx)
+    bit = jnp.where(forward, is_add, ~is_add).astype(jnp.int32)
+    return jnp.where(win, 2 * pos + bit, -1)
+
+
+def _lww_decide(key, old):
+    """Shared decision rule on the scatter-max of ``_lww_key`` (−1
+    where no in-window op touched the key): a decided key takes bit 0
+    of its key, an undecided one keeps ``old``."""
+    return jnp.where(key >= 0, (key & 1) == 1, old)
 
 
 @partial(jax.jit, static_argnames=("restrict_rows",))
@@ -76,7 +86,6 @@ def reconstruct_dense(anchor: DenseGraph, delta: Delta, t_anchor, t_query,
     reconstructed subgraph).
     """
     n = anchor.n_cap
-    m = delta.capacity
     forward = t_query >= t_anchor
     t_lo = jnp.minimum(t_anchor, t_query)
     t_hi = jnp.maximum(t_anchor, t_query)
@@ -86,35 +95,23 @@ def reconstruct_dense(anchor: DenseGraph, delta: Delta, t_anchor, t_query,
         touch = row_mask[delta.u] | row_mask[delta.v]
         in_win = in_win & touch
 
-    idx = jnp.arange(m, dtype=jnp.int32)
-
-    # ---- edges: scatter first/last op index per (u, v) cell ----
-    e_win = in_win & delta.is_edge_op()
-    e_first = jnp.where(e_win, idx, m)
-    e_last = jnp.where(e_win, idx, -1)
+    # ---- edges: scatter-max one key per (u, v) cell ----
+    e_key = _lww_key(in_win & delta.is_edge_op(), delta.op, forward,
+                     ADD_EDGE)
     with jax.named_scope("scatter"):
-        first = jnp.full((n, n), m, jnp.int32)
-        last = jnp.full((n, n), -1, jnp.int32)
-        first = first.at[delta.u, delta.v].min(e_first)
-        first = first.at[delta.v, delta.u].min(e_first)
-        last = last.at[delta.u, delta.v].max(e_last)
-        last = last.at[delta.v, delta.u].max(e_last)
+        key = jnp.full((n, n), -1, jnp.int32)
+        key = key.at[delta.u, delta.v].max(e_key)
+        key = key.at[delta.v, delta.u].max(e_key)
     with jax.named_scope("decide"):
-        decided, value = _lww_decide(first, last, delta.op, forward, m,
-                                     ADD_EDGE)
-        adj = jnp.where(decided, value, anchor.adj)
+        adj = _lww_decide(key, anchor.adj)
 
     # ---- nodes ----
-    n_win = in_win & delta.is_node_op()
-    n_first = jnp.where(n_win, idx, m)
-    n_last = jnp.where(n_win, idx, -1)
+    n_key = _lww_key(in_win & delta.is_node_op(), delta.op, forward,
+                     ADD_NODE)
     with jax.named_scope("scatter"):
-        firstn = jnp.full((n,), m, jnp.int32).at[delta.u].min(n_first)
-        lastn = jnp.full((n,), -1, jnp.int32).at[delta.u].max(n_last)
+        keyn = jnp.full((n,), -1, jnp.int32).at[delta.u].max(n_key)
     with jax.named_scope("decide"):
-        decided_n, value_n = _lww_decide(firstn, lastn, delta.op, forward,
-                                         m, ADD_NODE)
-        nodes = jnp.where(decided_n, value_n, anchor.nodes)
+        nodes = _lww_decide(keyn, anchor.nodes)
     return DenseGraph(nodes=nodes, adj=adj)
 
 
@@ -128,34 +125,26 @@ def reconstruct_edge(anchor: EdgeGraph, delta: Delta, t_anchor,
     O(E+N) state, independent of N²; this is the layout the distributed
     engine shards.
     """
-    m = delta.capacity
     forward = t_query >= t_anchor
     t_lo = jnp.minimum(t_anchor, t_query)
     t_hi = jnp.maximum(t_anchor, t_query)
     in_win = delta.window_mask(t_lo, t_hi) & delta.valid_mask()
-    idx = jnp.arange(m, dtype=jnp.int32)
 
-    e_win = in_win & delta.is_edge_op()
+    e_key = _lww_key(in_win & delta.is_edge_op(), delta.op, forward,
+                     ADD_EDGE)
     with jax.named_scope("scatter"):
-        first = jnp.full((anchor.e_cap,), m, jnp.int32)
-        last = jnp.full((anchor.e_cap,), -1, jnp.int32)
-        first = first.at[delta.slot].min(jnp.where(e_win, idx, m))
-        last = last.at[delta.slot].max(jnp.where(e_win, idx, -1))
+        key = jnp.full((anchor.e_cap,), -1, jnp.int32).at[
+            delta.slot].max(e_key)
     with jax.named_scope("decide"):
-        decided, value = _lww_decide(first, last, delta.op, forward, m,
-                                     ADD_EDGE)
-        emask = jnp.where(decided, value, anchor.emask)
+        emask = _lww_decide(key, anchor.emask)
 
-    n_win = in_win & delta.is_node_op()
+    n_key = _lww_key(in_win & delta.is_node_op(), delta.op, forward,
+                     ADD_NODE)
     with jax.named_scope("scatter"):
-        firstn = jnp.full((anchor.n_cap,), m, jnp.int32)
-        lastn = jnp.full((anchor.n_cap,), -1, jnp.int32)
-        firstn = firstn.at[delta.slot].min(jnp.where(n_win, idx, m))
-        lastn = lastn.at[delta.slot].max(jnp.where(n_win, idx, -1))
+        keyn = jnp.full((anchor.n_cap,), -1, jnp.int32).at[
+            delta.slot].max(n_key)
     with jax.named_scope("decide"):
-        decided_n, value_n = _lww_decide(firstn, lastn, delta.op, forward,
-                                         m, ADD_NODE)
-        nodes = jnp.where(decided_n, value_n, anchor.nodes)
+        nodes = _lww_decide(keyn, anchor.nodes)
     return dataclasses.replace(anchor, nodes=nodes, emask=emask)
 
 

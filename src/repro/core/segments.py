@@ -39,8 +39,9 @@ reference.
   for edge ops, the node id for node ops — only the FIRST and LAST op
   inside the node's span, in original log order: for any query window
   that fully covers the span, forward reconstruction is decided by the
-  key's last in-window op and backward reconstruction by its first
-  (``reconstruct._lww_decide``), and both survive the collapse exactly;
+  key's last in-window op and backward reconstruction by its first (the
+  scatter-max of ``reconstruct._lww_key``, whose keys order ops by log
+  position), and both survive the collapse exactly;
   every dropped interior op is superseded in both directions.  A window
   that only *partially* covers a node must not use it (a dropped
   interior op could be the window's first/last for its key), so

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 
 from repro.core.delta import ADD_EDGE, ADD_NODE, REM_EDGE, Delta
 from repro.core.graph import DenseGraph
+from repro.core.reconstruct import _lww_decide, _lww_key
 from repro.kernels.delta_apply.delta_apply import delta_apply_tiles
 
 
@@ -86,24 +87,13 @@ def _node_mask_lww(nodes, delta: Delta, t_lo, t_hi, forward: bool,
     """LWW node-mask update for rows [row0, row0 + len(nodes)) — the
     XLA path (N-sized, negligible next to the N² edge part)."""
     n_rows = nodes.shape[0]
-    m = delta.capacity
-    idx = jnp.arange(m, dtype=jnp.int32)
     in_win = delta.window_mask(t_lo, t_hi) & delta.valid_mask()
-    nwin = in_win & delta.is_node_op()
     lu = delta.u - row0
-    nwin = nwin & (lu >= 0) & (lu < n_rows)
+    nwin = in_win & delta.is_node_op() & (lu >= 0) & (lu < n_rows)
     lu = jnp.clip(lu, 0, n_rows - 1)
-    first = jnp.full((n_rows,), m, jnp.int32).at[lu].min(
-        jnp.where(nwin, idx, m))
-    last = jnp.full((n_rows,), -1, jnp.int32).at[lu].max(
-        jnp.where(nwin, idx, -1))
-    if forward:
-        dec = last >= 0
-        val = delta.op[jnp.clip(last, 0)] == ADD_NODE
-    else:
-        dec = first < m
-        val = delta.op[jnp.clip(first, None, m - 1)] != ADD_NODE
-    return jnp.where(dec, val, nodes)
+    key = jnp.full((n_rows,), -1, jnp.int32).at[lu].max(
+        _lww_key(nwin, delta.op, forward, ADD_NODE))
+    return _lww_decide(key, nodes)
 
 
 def delta_apply_row_block(nodes_block: jnp.ndarray, adj_block: jnp.ndarray,

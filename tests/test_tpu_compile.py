@@ -16,6 +16,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -115,3 +116,36 @@ def test_served_edge_program_fits_one_chip(one_chip):
         total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                  + mem.temp_size_in_bytes)
         assert total < V5E_HBM_BYTES, (measure, total)
+
+
+def test_dense_point_program_gathers_no_cell(one_chip):
+    """The dense point program of the analytics cell (``triangles``, a
+    group of 8, n_cap 5,120, delta capacity 8,192) decides each key
+    from its scattered key: no gather in the optimized program yields
+    n² or more elements (one per adjacency cell), and it fits one
+    chip's HBM."""
+    import re
+
+    from repro.core.delta import Delta
+    from repro.core.engine import batch_two_phase_point
+    from repro.core.graph import DenseGraph
+    n, m, b = 5120, 8192, 8
+
+    def arr(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    g = DenseGraph(nodes=arr((n,), jnp.bool_), adj=arr((n, n), jnp.bool_))
+    d = Delta(op=arr((m,)), u=arr((m,)), v=arr((m,)), slot=arr((m,)),
+              t=arr((m,)), n_ops=arr(()))
+    compiled = batch_two_phase_point.lower(
+        g, d, arr(()), arr((b,)), arr((b,)), measure="triangles",
+        scope="global").compile()
+    gathers = re.findall(r"=\s*\w+\[([\d,]*)\]\S*\s+gather\(",
+                         compiled.as_text())
+    sizes = [int(np.prod([int(x) for x in dims.split(",") if x]))
+             for dims in gathers]
+    assert all(s < n * n for s in sizes), sizes
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < V5E_HBM_BYTES, total
